@@ -7,11 +7,14 @@ sublinearity check (HNSW latency ~ O(log n)).  Alongside the paper's
 per-query walk we time the unified engine's batched path (DESIGN.md §2):
 same HNSW filter, one jitted refine for the whole batch.
 
-The sharded suite needs more than one XLA device, which must be forced
-*before* jax initializes — so `run_sharded()` re-executes this module in
-a subprocess with `XLA_FLAGS=--xla_force_host_platform_device_count=8`
-and collects its rows (`python -m benchmarks.bench_scalability
---sharded` runs the measurement directly)."""
+The sharded suite needs more than one XLA device.  On accelerators it
+runs in this process over the real chips: a chip belongs to one process,
+so a child could not reach it while this one holds it.  When JAX's
+backend is the CPU, the devices must be forced *before* jax
+initializes, so `run_sharded()` re-executes this module in a CPU-only
+child with `XLA_FLAGS=--xla_force_host_platform_device_count=8` and
+collects its rows (`python -m benchmarks.bench_scalability --sharded`
+runs the measurement directly)."""
 
 from __future__ import annotations
 
@@ -125,10 +128,16 @@ def _run_sharded_inproc(n: int, nq: int, shards=(1, 2, 8)) -> list[str]:
 
 
 def run_sharded(n: int = 6000, nq: int = 16) -> list[str]:
-    """Re-exec this module with 8 forced host devices and collect the
-    sharded suite rows (jax pins its device count at first init, so the
-    flag cannot be set in-process once any other suite has run)."""
-    env = dict(os.environ)
+    """The sharded suite rows: in-process over the real devices, or —
+    on a CPU run — from a CPU-only child with 8 forced host devices
+    (jax pins its device count at first init, so the flag cannot be set
+    in-process once any other suite has run)."""
+    import jax
+    if jax.default_backend() != "cpu":
+        n_dev = jax.device_count()
+        shards = tuple(s for s in (1, 2, 4, 8) if s <= n_dev)
+        return _run_sharded_inproc(n, nq, shards=shards)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8"
                         ).strip()

@@ -91,6 +91,9 @@ def main() -> None:
                     help="directory for BENCH_<suite>.json records")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (bench_attacks, bench_baselines, bench_batched,
                    bench_beta, bench_encrypt, bench_filter, bench_graph,
                    bench_kernels, bench_profile, bench_ratio_k,
@@ -110,9 +113,9 @@ def main() -> None:
         "fig10_scalability": lambda: bench_scalability.run(
             sizes=(10000, 20000, 40000, 80000) if args.full
             else (5000, 10000, 20000, 40000)),
-        # mesh-sharded placement over 1/2/8 simulated devices (runs in a
-        # subprocess so the forced device count cannot leak into the
-        # other suites' jax state) — DESIGN.md §10
+        # mesh-sharded placement (DESIGN.md §10): in this process over
+        # the real accelerators, or over 8 simulated CPU devices in a
+        # child process on a CPU run
         "sharded": lambda: bench_scalability.run_sharded(
             n=16000 if args.full else 6000),
         # quantized ADC filter path: f32 vs int8 vs pq8 (DESIGN.md §11);

@@ -252,12 +252,13 @@ def _encrypt_jax_core(X, perm1, perm2, M1, M2, M3, r, kv, rng_key):
         [hat[:, half:], alpha[:, 1:], alpha[:, 1:], rp[:, 2:3], gamma],
         axis=1)
     # Step 4 (Eq. 4): p̄ = pi2([p̂1ᵀ M1 ; p̂2ᵀ M2]).
-    t = jnp.concatenate([h1 @ M1, h2 @ M2], axis=1)
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    t = jnp.concatenate([mm(h1, M1), mm(h2, M2)], axis=1)
     bar = jnp.take(t, perm2, axis=1)
 
     # Component split (Eq. 10 / Eq. 13).
-    up = bar @ M3[: d + 8]
-    down = bar @ M3[d + 8:]
+    up = mm(bar, M3[: d + 8])
+    down = mm(bar, M3[d + 8:])
     r_p = jax.random.uniform(k_scale, (n, 1), minval=0.5, maxval=2.0)
     C = jnp.stack(
         [

@@ -5,9 +5,7 @@
 (bit-identical `.ppcol` round-trip with `core.hnsw`); `traverse` the
 jitted lockstep walk (upper-layer greedy descent + layer-0 beam
 search, perf and oblivious variants); `filter` the
-`SecureSearchEngine` backend.  The Pallas frontier-expansion kernel
-lives in `kernels.graph_expand` and is dispatched through its ops
-wrapper.
+`SecureSearchEngine` backend.
 """
 
 from . import traverse  # noqa: F401  (before filter: import-cycle order)

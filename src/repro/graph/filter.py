@@ -7,8 +7,7 @@ the CSR mirror for the whole query batch instead of a Python loop of
 per-query host walks.  That buys the graph index everything the other
 backends already had:
 
-  * batching — beams expand for all queries per hop (`graph.traverse`,
-    or the graph_expand Pallas kernel on TPU);
+  * batching — beams expand for all queries per hop (`graph.traverse`);
   * quantization — edges scored with the ADC int8/pq8 surrogates of
     `core.adc` (codebook trained keylessly at attach, exactly like
     `ADCFilter`), with the same oversample-then-exact-refine contract;
@@ -26,14 +25,13 @@ in `graph.traverse`.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core import adc
 from ..core.hnsw import HNSW
 from .csr import CSRGraph
-from .traverse import beam_plan
+from .traverse import beam_plan, graph_topk
 
 __all__ = ["GraphFilter"]
 
@@ -47,14 +45,11 @@ class GraphFilter:
     oblivious: bounded-hop fixed-fanout traversal (the `hardened`
     profile's tier); returned ids are bit-identical to the perf
     variant (the latched-freeze contract in `graph.traverse`).
-    use_kernel=True engages the Pallas frontier kernel on actual TPU
-    backends (f32 mode); elsewhere the XLA lockstep walk runs.
     """
 
     def __init__(self, index: HNSW, *, quantization: str | None = None,
                  refine_ratio: float | None = None, pq_m: int = 16,
-                 use_kernel: bool = True, oblivious: bool = False,
-                 seed: int = 0):
+                 oblivious: bool = False, seed: int = 0):
         if quantization not in (None, "int8", "pq8"):
             raise ValueError(f"GraphFilter quantization must be "
                              f"None|int8|pq8, got {quantization!r}")
@@ -68,7 +63,6 @@ class GraphFilter:
             else adc.default_refine_ratio(quantization)
             if quantization is not None else 1.0)
         self.pq_m = pq_m
-        self.use_kernel = use_kernel
         self.oblivious = oblivious
         self.seed = seed
         self.codebook = None
@@ -82,9 +76,6 @@ class GraphFilter:
         self.last_scan_trace: np.ndarray | None = None
 
     # --------------------------------------------------------------- setup
-
-    def _use_pallas(self) -> bool:
-        return self.use_kernel and jax.default_backend() == "tpu"
 
     def oversampled(self, kp: int) -> int:
         return max(kp, int(np.ceil(kp * self.refine_ratio)))
@@ -131,18 +122,17 @@ class GraphFilter:
         return jnp.asarray(self.codebook.lut(Q))
 
     def candidates(self, Q_sap: np.ndarray, kp: int, ef_search: int):
-        from ..kernels.graph_expand import ops as graph_ops
         Q = np.asarray(Q_sap, np.float32)
         nq = Q.shape[0]
         g = self.csr
         kp2 = max(1, min(self.oversampled(kp), max(g.n, 1)))
         ef_eff, ef_cap, max_hops = beam_plan(kp2, max(ef_search, kp2))
-        cand, _, visited, hops, edges = graph_ops.graph_topk(
+        cand, _, visited, hops, edges = graph_topk(
             self._neigh0, self._neigh_up, self._ok, self._db,
             self._query_operand(Q), jnp.int32(g.entry),
             jnp.int32(ef_eff), kp=kp2, ef_cap=ef_cap,
             max_hops=max_hops, quant=self.quant,
-            oblivious=self.oblivious, use_kernel=self._use_pallas())
+            oblivious=self.oblivious)
         cand = np.asarray(cand, np.int32)
         valid = cand >= 0
         cand = np.where(valid, cand, 0)

@@ -166,9 +166,7 @@ def beam_layer0(neigh0, ok, db, qd, ep, ep_d, ef, *, kp: int,
                 ef_cap: int, max_hops: int, quant: str = "f32",
                 oblivious: bool = False, hops=None, edges=None):
     """Phase 2: lockstep best-first beam search over the layer-0 rows,
-    starting each query at its descent endpoint ep/ep_d.  This is the
-    phase the graph_expand Pallas kernel replaces on TPU (the XLA form
-    here is the serving path everywhere else).
+    starting each query at its descent endpoint ep/ep_d.
 
     Returns (cand (nq, kp) int32 with -1 fill, cand_d (nq, kp) f32
     (+inf fill), visited (nq, R) bool scan trace, hops, edges).
@@ -289,3 +287,10 @@ def traverse(neigh0, neigh_up, ok, db, qd, entry, ef, *, kp: int,
 graph_topk = jax.jit(
     traverse,
     static_argnames=("kp", "ef_cap", "max_hops", "quant", "oblivious"))
+
+# Opt-in kernel profiling (repro.obs, DESIGN.md §13): strict
+# passthrough unless a KernelProfiler is active; `_cache_size` is
+# preserved for the recompile audit.
+from ..obs.profiler import instrument as _instrument  # noqa: E402
+
+graph_topk = _instrument("graph.graph_topk", graph_topk)

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from ..common import LANE, interpret_default, pad_to, padded_size
+from ..common import HIGHEST, LANE, interpret_default, pad_to, padded_size
 
 DEFAULT_BLOCK_N_SQ = 512
 DEFAULT_BLOCK_N_PQ = 128
@@ -142,7 +142,7 @@ def _pq_adc_kernel(lut_ref, codes_ref, ok_ref, best_d_ref, best_i_ref):
     d_blk = jax.lax.dot_general(
         lut_ref[...], onehot,
         dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )                                                  # (nq_p, bn)
     d_blk = jnp.where(ok_ref[...] > 0, d_blk, jnp.inf)
     gcol = pi * bn + jax.lax.broadcasted_iota(jnp.int32, d_blk.shape, 1)

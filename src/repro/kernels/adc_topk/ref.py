@@ -40,7 +40,9 @@ def pq_dists(lut: np.ndarray, codes_t: np.ndarray) -> np.ndarray:
 
 
 def _topk_ascending(d, k: int):
-    neg, idx = jax.lax.top_k(-jnp.asarray(d), k)
+    d = jnp.asarray(d)
+    # k clamps to the row count, as the kernels' kp = min(kp, n) does
+    neg, idx = jax.lax.top_k(-d, min(k, d.shape[-1]))
     return -neg, idx.astype(jnp.int32)
 
 

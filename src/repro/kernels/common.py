@@ -13,6 +13,12 @@ import jax.numpy as jnp
 LANE = 128
 SUBLANE = 8
 
+# f32 matmul precision for every ciphertext product.  The TPU's default
+# for f32 operands is a single bf16 pass, which rounds DCPE distances and
+# DCE Z-scores far past the gaps the filter ranks and the refine's exact
+# comparisons resolve; HIGHEST keeps full f32 (CPU computes f32 anyway).
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def interpret_default() -> bool:
     """Run pallas in interpret mode unless we are actually on TPU."""

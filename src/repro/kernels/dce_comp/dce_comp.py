@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import LANE, interpret_default, pad_to, padded_size
+from ..common import HIGHEST, LANE, interpret_default, pad_to, padded_size
 
 DEFAULT_BLOCK = 128
 
@@ -35,10 +35,10 @@ def _z_tile_kernel(c1_ref, c2_ref, c3_ref, c4_ref, t_ref, out_ref):
     left2 = c2_ref[...] * t
     term1 = jax.lax.dot_general(
         left1, c3_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
     term2 = jax.lax.dot_general(
         left2, c4_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
     out_ref[...] = term1 - term2
 
 
@@ -87,15 +87,15 @@ def _z_tile_kernel_batched(c1_ref, c2_ref, c3_ref, c4_ref, t_ref, out_ref):
     Identical math to `_z_tile_kernel`, with a leading batch grid dim
     selecting which query's candidate set and trapdoor are resident.
     """
-    t = t_ref[...]                       # (1, D)
+    t = t_ref[0]                         # (1, D)
     left1 = c1_ref[0] * t                # fused trapdoor scaling
     left2 = c2_ref[0] * t
     term1 = jax.lax.dot_general(
         left1, c3_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
     term2 = jax.lax.dot_general(
         left2, c4_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
     out_ref[0] = term1 - term2
 
 
@@ -123,7 +123,10 @@ def batched_z_matrix(
 
     blk = min(block, max(LANE, padded_size(n, LANE)))
     Cp = pad_to(pad_to(Cf, 1, blk), 3, LANE)
-    Tp = pad_to(Tf, 1, LANE)
+    # trapdoors as (B, 1, D_p): a (1, 1, D_p) block keeps the last two
+    # block dims equal to the array's, the TPU tiling rule a (1, D_p)
+    # block over a (B, D_p) array breaks for every B > 1
+    Tp = pad_to(Tf, 1, LANE)[:, None, :]
     _, n_p, _, D_p = Cp.shape
     comps = [Cp[:, :, i, :] for i in range(4)]   # (B, n_p, D_p) each
 
@@ -136,7 +139,7 @@ def batched_z_matrix(
             pl.BlockSpec((1, blk, D_p), lambda b, i, j: (b, i, 0)),  # C2 rows
             pl.BlockSpec((1, blk, D_p), lambda b, i, j: (b, j, 0)),  # C3 cols
             pl.BlockSpec((1, blk, D_p), lambda b, i, j: (b, j, 0)),  # C4 cols
-            pl.BlockSpec((1, D_p), lambda b, i, j: (b, 0)),          # trapdoor
+            pl.BlockSpec((1, 1, D_p), lambda b, i, j: (b, 0, 0)),    # trapdoor
         ],
         out_specs=pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, n_p, n_p), jnp.float32),

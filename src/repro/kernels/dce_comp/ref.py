@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..common import HIGHEST
+
 
 def z_matrix(C: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     """All-pairs DCE Z-scores.  C: (n, 4, D), t: (D,) -> (n, n).
@@ -13,8 +15,8 @@ def z_matrix(C: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     """
     C = C.astype(jnp.float32)
     t = t.astype(jnp.float32)
-    term1 = (C[:, 0, :] * t) @ C[:, 2, :].T
-    term2 = (C[:, 1, :] * t) @ C[:, 3, :].T
+    term1 = jnp.matmul(C[:, 0, :] * t, C[:, 2, :].T, precision=HIGHEST)
+    term2 = jnp.matmul(C[:, 1, :] * t, C[:, 3, :].T, precision=HIGHEST)
     return term1 - term2
 
 
@@ -43,6 +45,6 @@ def batched_z_matrix(C: jnp.ndarray, T: jnp.ndarray) -> jnp.ndarray:
     T = T.astype(jnp.float32)
     left1 = C[:, :, 0, :] * T[:, None, :]
     left2 = C[:, :, 1, :] * T[:, None, :]
-    z1 = jnp.einsum("bkd,bjd->bkj", left1, C[:, :, 2, :])
-    z2 = jnp.einsum("bkd,bjd->bkj", left2, C[:, :, 3, :])
+    z1 = jnp.einsum("bkd,bjd->bkj", left1, C[:, :, 2, :], precision=HIGHEST)
+    z2 = jnp.einsum("bkd,bjd->bkj", left2, C[:, :, 3, :], precision=HIGHEST)
     return z1 - z2

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import LANE, interpret_default, pad_to, padded_size
+from ..common import HIGHEST, LANE, interpret_default, pad_to, padded_size
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_N = 128
@@ -36,7 +36,7 @@ def _l2_tile_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref):
     cross = jax.lax.dot_general(
         q_ref[...], x_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
     out_ref[...] = qn_ref[...] - 2.0 * cross + xn_ref[...]
 

@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..common import HIGHEST
+
 
 def pairwise_sq_dists(Q: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
     """||q - x||^2 for all pairs; Q: (nq, d), X: (n, d) -> (nq, n)."""
@@ -12,7 +14,7 @@ def pairwise_sq_dists(Q: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
     X = X.astype(jnp.float32)
     qn = (Q * Q).sum(-1, keepdims=True)
     xn = (X * X).sum(-1)[None, :]
-    return qn - 2.0 * Q @ X.T + xn
+    return qn - 2.0 * jnp.matmul(Q, X.T, precision=HIGHEST) + xn
 
 
 def knn(Q: jnp.ndarray, X: jnp.ndarray, k: int):

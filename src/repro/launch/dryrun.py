@@ -111,12 +111,8 @@ _GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict — newer jax returns the
-    dict directly, 0.4.x returns a one-element list of dicts."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """compiled.cost_analysis() as a dict ({} when XLA reports none)."""
+    return compiled.cost_analysis() or {}
 
 
 def parse_collectives(hlo_text: str) -> dict:

@@ -10,14 +10,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where this jax version
-    supports them (jax.sharding.AxisType is newer than 0.4.x; Auto is the
-    default behavior either way)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with explicit Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

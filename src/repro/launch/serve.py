@@ -23,6 +23,7 @@ from repro.api import (DataOwnerClient, IndexSpec, PlacementSpec,
                        SearchParams, SecureAnnService, suggest_beta)
 from repro.configs import get_config
 from repro.data import synth
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model
 from repro.serving import LMServer
 
@@ -47,6 +48,7 @@ def main(argv=None):
                     help="write a Chrome-trace JSON of the ANN sidecar's "
                          "request spans to this path on exit")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch).smoke()
     model = Model(cfg)

@@ -38,7 +38,7 @@ from ...core import adc
 from ...core.hnsw import HNSW
 from ...core.ivf import IVFIndex
 from ...graph.csr import CSRGraph
-from ...graph.traverse import beam_plan
+from ...graph.traverse import beam_plan, graph_topk
 from ...kernels.adc_topk import ops as adc_ops
 from ...kernels.common import next_bucket
 from ...kernels.l2_topk import ops as l2_ops
@@ -802,10 +802,9 @@ class DeltaAwareBackend:
     def _candidates_graph(self, Q_sap: np.ndarray, kp: int,
                           ef_search: int):
         """Batched lockstep traversal over the CSR mirror (the whole
-        query batch in one jitted call — `kernels.graph_expand.ops`).
+        query batch in one jitted call — `graph.traverse.graph_topk`).
         Static args are buckets only; ef/entry/validity are data, so
         steady-state serving reuses one executable."""
-        from ...kernels.graph_expand import ops as graph_ops
         st = self.store
         Q = np.asarray(Q_sap, np.float32)
         nq = Q.shape[0]
@@ -818,12 +817,11 @@ class DeltaAwareBackend:
             qd = jnp.asarray(self.adc_codebook.encode_query(Q))
         else:
             qd = jnp.asarray(self.adc_codebook.lut(Q))
-        cand, _, visited, hops, edges = graph_ops.graph_topk(
+        cand, _, visited, hops, edges = graph_topk(
             self._g_neigh0, self._g_neigh_up, self._g_ok, self._g_db,
             qd, jnp.int32(self._csr.entry), jnp.int32(ef_eff),
             kp=kp2, ef_cap=ef_cap, max_hops=max_hops,
-            quant=self.quantization or "f32",
-            oblivious=self.oblivious, use_kernel=self._use_pallas())
+            quant=self.quantization or "f32", oblivious=self.oblivious)
         safe, valid = self._mask_alive(np.asarray(cand, np.int32),
                                        np.asarray(cand) >= 0)
         n_edges = int(np.asarray(edges).sum())

@@ -48,15 +48,15 @@ def jit_cache_size() -> int:
     """Total cached-executable count across the runtime's jitted entry
     points.  A steady value across a traffic phase == zero recompiles."""
     from ...core import dce, dcpe
+    from ...graph.traverse import graph_topk
     from ...kernels.adc_topk import ops as adc_ops
     from ...kernels.dce_comp import ops as dce_ops
-    from ...kernels.graph_expand import ops as graph_ops
     from ...kernels.l2_topk import ops as l2_ops
     from .. import search_engine as se
     from .. import sharded
 
     fns = (
-        graph_ops.graph_topk,
+        graph_topk,
         se.refine_candidates,
         se._masked_pruned_scan,
         se._masked_full_scan,

@@ -41,7 +41,7 @@ from ..core import adc, secure_knn
 from ..core.hnsw import HNSW
 from ..core.ivf import IVFIndex
 from ..kernels.adc_topk import ops as adc_ops
-from ..kernels.common import next_bucket
+from ..kernels.common import HIGHEST, next_bucket
 from ..kernels.dce_comp import ops as dce_ops
 from ..kernels.l2_topk import ops as l2_ops
 from ..obs.trace import child_span
@@ -119,19 +119,40 @@ def refine_candidates(C_dce, cand, T, valid, k: int, use_kernel: bool = True):
     return jnp.where(vsel, ids, -1)
 
 
+# Most bytes of gathered probe rows one step of a pool scan holds.  At
+# 1M rows a probe pool buckets to L = 2^19, so a 64-query batch would
+# gather 16 GiB at once — more than a v5e chip's HBM.
+POOL_GATHER_BYTES = 1 << 30
+
+
+def pool_dists(C, Q, idx, mask):
+    """(nq, L) ciphertext distances ||q||^2 - 2 q.x + ||x||^2 from each
+    query to its gathered rows C[idx] (+inf where ~mask) — the l2_topk
+    restructuring over a per-query gather, since each query probes
+    different partitions.  Runs a few queries per step so the (step, L,
+    d) gather stays under POOL_GATHER_BYTES whatever the batch; every
+    pool scan (single-device and sharded) computes through here, so
+    their floats agree element for element."""
+    nq, L = idx.shape
+    step = max(1, min(nq, POOL_GATHER_BYTES
+                      // (L * C.shape[1] * C.dtype.itemsize)))
+
+    def one(args):
+        q, i, m = args
+        rows = jnp.take(C, i, axis=0)                   # (L, d)
+        xn = (rows * rows).sum(-1)
+        cross = jnp.einsum("ld,d->l", rows, q, precision=HIGHEST)
+        return jnp.where(m, (q * q).sum() - 2.0 * cross + xn, jnp.inf)
+
+    return jax.lax.map(one, (Q, idx, mask), batch_size=step)
+
+
 @functools.partial(jax.jit, static_argnames=("kp",))
 def _masked_pruned_scan(C_sap, Q, cand, valid, kp: int):
-    """IVF filter inner loop: ciphertext distances over probed rows only.
-
-    Same ||q||^2 - 2 q.x + ||x||^2 restructuring as the l2_topk kernel,
-    with a per-query gather (each query probes different partitions) and
-    an invalid-slot mask.  Returns (ids, valid) of the per-query top-kp.
-    """
-    rows = jnp.take(C_sap, cand, axis=0)                # (nq, L, d)
-    qn = (Q * Q).sum(-1)[:, None]
-    xn = (rows * rows).sum(-1)
-    cross = jnp.einsum("qld,qd->ql", rows, Q)
-    d = jnp.where(valid, qn - 2.0 * cross + xn, jnp.inf)
+    """IVF filter inner loop: ciphertext distances over probed rows only
+    (`pool_dists`), masked at invalid slots.  Returns (ids, valid) of the
+    per-query top-kp."""
+    d = pool_dists(C_sap, Q, cand, valid)
     kp = min(kp, d.shape[1])
     _, pos = jax.lax.top_k(-d, kp)
     return (jnp.take_along_axis(cand, pos, axis=1),
@@ -199,7 +220,7 @@ def _masked_full_scan(C_all, Q, member, kp: int):
     """
     qn = (Q * Q).sum(-1)[:, None]
     xn = (C_all * C_all).sum(-1)[None, :]
-    d = qn - 2.0 * Q @ C_all.T + xn                     # (nq, bucket)
+    d = qn - 2.0 * jnp.matmul(Q, C_all.T, precision=HIGHEST) + xn
     d = jnp.where(member, d, jnp.inf)
     kp = min(kp, d.shape[1])
     _, pos = jax.lax.top_k(-d, kp)

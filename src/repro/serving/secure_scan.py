@@ -29,8 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
+from ..kernels.common import HIGHEST
 from ..kernels.dce_comp import ops as dce_ops
 
 __all__ = ["build_secure_scan_step", "secure_scan_input_specs"]
@@ -66,7 +67,7 @@ def build_secure_scan_step_gspmd(mesh: Mesh, *, k: int, k_prime: int):
     def step(C_sap, C_dce, Q_sap, T_q):
         qn = (Q_sap * Q_sap).sum(-1, keepdims=True)
         xn = (C_sap * C_sap).sum(-1)[None, :]
-        dist = qn - 2.0 * Q_sap @ C_sap.T + xn            # (B, n) global
+        dist = qn - 2.0 * jnp.matmul(Q_sap, C_sap.T, precision=HIGHEST) + xn
         _, cand = jax.lax.top_k(-dist, k_prime)
         Cc = jnp.take(C_dce, cand, axis=0)
         top = dce_ops.batched_top_k_by_wins(Cc, T_q, k, use_kernel=False)
@@ -89,13 +90,13 @@ def build_secure_scan_step(mesh: Mesh, *, k: int, k_prime: int):
         shard_map, mesh=mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False)
+        check_vma=False)
     def filter_local(C_sap_loc, Q):
         """Per-shard filter + global candidate merge."""
         n_loc = C_sap_loc.shape[0]
         qn = (Q * Q).sum(-1, keepdims=True)
         xn = (C_sap_loc * C_sap_loc).sum(-1)[None, :]
-        dist = qn - 2.0 * Q @ C_sap_loc.T + xn            # (B, n_loc)
+        dist = qn - 2.0 * jnp.matmul(Q, C_sap_loc.T, precision=HIGHEST) + xn
         kp = min(k_prime, n_loc)
         neg, idx = jax.lax.top_k(-dist, kp)               # local top-k'
         gidx = idx + _shard_index() * n_loc

@@ -33,9 +33,9 @@ runtime all work unchanged over a sharded collection.  What changes is
                   extracts the candidate rows it owns (others zeroed)
                   and one `psum` of (nq, k', 4, D) — k' rows per query,
                   never the database — assembles the replicated
-                  candidate tensor for the batched tournament (einsum
-                  formulation: a Pallas call over mesh-sharded gathers
-                  would fight the partitioner, DESIGN.md §3).
+                  candidate tensor for the batched tournament, which
+                  every device runs with the single-device refine's
+                  own kernel (identical comparisons, identical ids).
 
 Row -> shard routing is the block partition of the padded capacity
 bucket: global row id r lives on shard `r // rows_per_shard`.  Ids are
@@ -67,20 +67,20 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.hnsw import HNSW
 from ..graph.csr import CSRGraph
-from ..graph.traverse import beam_plan
+from ..graph.traverse import beam_plan, graph_topk
 from ..kernels.adc_topk.ops import INT_BIG
-from ..kernels.common import next_bucket
+from ..kernels.common import HIGHEST, next_bucket
 from ..kernels.dce_comp import ops as dce_ops
 from ..launch.mesh import make_mesh
 from ..obs.trace import child_complete, current as obs_current
 from ..resilience.health import ShardHealthRegistry
 from .runtime.ingest import SENTINEL, DeltaAwareBackend
-from .search_engine import layout_pools, pool_membership
+from .search_engine import layout_pools, pool_dists, pool_membership
 
 __all__ = ["ShardedBackend", "sharded_mesh", "shard_bucket"]
 
@@ -119,7 +119,7 @@ def _sharded_flat_topk(C_sh, Q, *, mesh, axis, kp: int):
         n_loc = C_loc.shape[0]
         qn = (Q_rep * Q_rep).sum(-1, keepdims=True)
         xn = (C_loc * C_loc).sum(-1)[None, :]
-        dist = qn - 2.0 * Q_rep @ C_loc.T + xn            # (nq, n_loc)
+        dist = qn - 2.0 * jnp.matmul(Q_rep, C_loc.T, precision=HIGHEST) + xn
         kp_loc = min(kp, n_loc)
         neg, idx = jax.lax.top_k(-dist, kp_loc)           # local top-k'
         gidx = idx + jax.lax.axis_index(axis) * n_loc     # global ids
@@ -131,7 +131,7 @@ def _sharded_flat_topk(C_sh, Q, *, mesh, axis, kp: int):
     return shard_map(body, mesh=mesh,
                      in_specs=(P(axis, None), P(None, None)),
                      out_specs=P(None, None),
-                     check_rep=False)(C_sh, Q)
+                     check_vma=False)(C_sh, Q)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -145,7 +145,7 @@ def _sharded_flat_topk_ok(C_sh, ok_sh, Q, *, mesh, axis, kp: int):
         n_loc = C_loc.shape[0]
         qn = (Q_rep * Q_rep).sum(-1, keepdims=True)
         xn = (C_loc * C_loc).sum(-1)[None, :]
-        dist = qn - 2.0 * Q_rep @ C_loc.T + xn            # (nq, n_loc)
+        dist = qn - 2.0 * jnp.matmul(Q_rep, C_loc.T, precision=HIGHEST) + xn
         dist = jnp.where(ok_loc[None, :], dist, jnp.inf)
         kp_loc = min(kp, n_loc)
         neg, idx = jax.lax.top_k(-dist, kp_loc)
@@ -154,7 +154,7 @@ def _sharded_flat_topk_ok(C_sh, ok_sh, Q, *, mesh, axis, kp: int):
     return shard_map(body, mesh=mesh,
                      in_specs=(P(axis, None), P(axis), P(None, None)),
                      out_specs=P(None, None),
-                     check_rep=False)(C_sh, ok_sh, Q)
+                     check_vma=False)(C_sh, ok_sh, Q)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -170,11 +170,7 @@ def _sharded_pool_scan(C_sh, Q, cand, valid, *, mesh, axis, kp: int):
         base = jax.lax.axis_index(axis) * n_loc
         loc = cand_rep - base
         mine = (loc >= 0) & (loc < n_loc) & valid_rep
-        rows = jnp.take(C_loc, jnp.clip(loc, 0, n_loc - 1), axis=0)
-        qn = (Q_rep * Q_rep).sum(-1)[:, None]
-        xn = (rows * rows).sum(-1)
-        cross = jnp.einsum("qld,qd->ql", rows, Q_rep)
-        d = jnp.where(mine, qn - 2.0 * cross + xn, jnp.inf)
+        d = pool_dists(C_loc, Q_rep, jnp.clip(loc, 0, n_loc - 1), mine)
         d = jax.lax.pmin(d, axis)                         # (nq, L) full
         kp_out = min(kp, d.shape[1])
         _, pos = jax.lax.top_k(-d, kp_out)
@@ -185,7 +181,7 @@ def _sharded_pool_scan(C_sh, Q, cand, valid, *, mesh, axis, kp: int):
                      in_specs=(P(axis, None), P(None, None),
                                P(None, None), P(None, None)),
                      out_specs=(P(None, None), P(None, None)),
-                     check_rep=False)(C_sh, Q, cand, valid)
+                     check_vma=False)(C_sh, Q, cand, valid)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -201,7 +197,7 @@ def _sharded_oblivious_scan(C_sh, Q, member, *, mesh, axis, kp: int):
         n_loc = C_loc.shape[0]
         qn = (Q_rep * Q_rep).sum(-1, keepdims=True)
         xn = (C_loc * C_loc).sum(-1)[None, :]
-        d = qn - 2.0 * Q_rep @ C_loc.T + xn               # (nq, n_loc)
+        d = qn - 2.0 * jnp.matmul(Q_rep, C_loc.T, precision=HIGHEST) + xn
         d = jnp.where(m_loc, d, jnp.inf)
         kp_loc = min(kp, n_loc)
         neg, idx = jax.lax.top_k(-d, kp_loc)
@@ -211,7 +207,7 @@ def _sharded_oblivious_scan(C_sh, Q, member, *, mesh, axis, kp: int):
                      in_specs=(P(axis, None), P(None, None),
                                P(None, axis)),
                      out_specs=P(None, None),
-                     check_rep=False)(C_sh, Q, member)
+                     check_vma=False)(C_sh, Q, member)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -236,7 +232,7 @@ def _sharded_sq_oblivious(C8_sh, cn_sh, Q8, member, *, mesh, axis,
                      in_specs=(P(axis, None), P(axis), P(None, None),
                                P(None, axis)),
                      out_specs=P(None, None),
-                     check_rep=False)(C8_sh, cn_sh, Q8, member)
+                     check_vma=False)(C8_sh, cn_sh, Q8, member)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -259,16 +255,21 @@ def _sharded_pq_oblivious(codes_t_sh, lut, member, *, mesh, axis,
                      in_specs=(P(None, axis), P(None, None, None),
                                P(None, axis)),
                      out_specs=P(None, None),
-                     check_rep=False)(codes_t_sh, lut, member)
+                     check_vma=False)(codes_t_sh, lut, member)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "k"))
 def _sharded_refine(C_dce_sh, cand, T, valid, *, mesh, axis, k: int):
     """Sharded batched DCE tournament: per-shard candidate-row extraction
     + one psum of (nq, k', 4, D) assembles the replicated candidate
-    tensor; the tournament itself (einsum Z-matrices, win-count ranking)
-    runs replicated.  Same -1 semantics as `search_engine
-    .refine_candidates` with a validity mask."""
+    tensor; the tournament itself runs replicated on every device, as
+    the very `batched_top_k_by_wins` the single-device refine runs (the
+    dce_comp kernel on TPU).  f32 DCE comparisons resolve distance gaps
+    only down to ~1e-4 relative, so two Z formulations can order a
+    near-tie differently; one formulation keeps sharded ids identical
+    to single-device ids.  A Pallas kernel cannot be auto-partitioned,
+    hence the shard_map over replicated operands.  Same -1 semantics as
+    `search_engine.refine_candidates` with a validity mask."""
 
     def gather(C_loc, cand_rep):
         n_loc = C_loc.shape[0]
@@ -282,10 +283,12 @@ def _sharded_refine(C_dce_sh, cand, T, valid, *, mesh, axis, k: int):
     Cc = shard_map(gather, mesh=mesh,
                    in_specs=(P(axis, None, None), P(None, None)),
                    out_specs=P(None, None, None, None),
-                   check_rep=False)(C_dce_sh, cand)
-    local = dce_ops.batched_top_k_by_wins(Cc, T, k, valid=valid,
-                                          use_kernel=False)
-    local = local.astype(cand.dtype)
+                   check_vma=False)(C_dce_sh, cand)
+    tournament = shard_map(
+        lambda c, t, v: dce_ops.batched_top_k_by_wins(c, t, k, valid=v),
+        mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
+        check_vma=False)
+    local = tournament(Cc, T, valid).astype(cand.dtype)
     ids = jnp.take_along_axis(cand, local, axis=1)
     vsel = jnp.take_along_axis(valid, local, axis=1)
     return jnp.where(vsel, ids, -1)
@@ -344,7 +347,7 @@ def _sharded_sq_topk(C8_sh, cn_sh, ok_sh, Q8, *, mesh, axis, kp: int):
                      in_specs=(P(axis, None), P(axis), P(axis),
                                P(None, None)),
                      out_specs=P(None, None),
-                     check_rep=False)(C8_sh, cn_sh, ok_sh, Q8)
+                     check_vma=False)(C8_sh, cn_sh, ok_sh, Q8)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -366,7 +369,7 @@ def _sharded_pq_topk(codes_t_sh, ok_sh, lut, *, mesh, axis, kp: int):
     return shard_map(body, mesh=mesh,
                      in_specs=(P(None, axis), P(axis), P(None, None, None)),
                      out_specs=P(None, None),
-                     check_rep=False)(codes_t_sh, ok_sh, lut)
+                     check_vma=False)(codes_t_sh, ok_sh, lut)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -396,7 +399,7 @@ def _sharded_sq_pool_scan(C8_sh, cn_sh, Q8, cand, valid, *, mesh, axis,
                      in_specs=(P(axis, None), P(axis), P(None, None),
                                P(None, None), P(None, None)),
                      out_specs=(P(None, None), P(None, None)),
-                     check_rep=False)(C8_sh, cn_sh, Q8, cand, valid)
+                     check_vma=False)(C8_sh, cn_sh, Q8, cand, valid)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "kp"))
@@ -425,7 +428,7 @@ def _sharded_pq_pool_scan(codes_t_sh, lut, cand, valid, *, mesh, axis,
                      in_specs=(P(None, axis), P(None, None, None),
                                P(None, None), P(None, None)),
                      out_specs=(P(None, None), P(None, None)),
-                     check_rep=False)(codes_t_sh, lut, cand, valid)
+                     check_vma=False)(codes_t_sh, lut, cand, valid)
 
 
 def cache_size() -> int:
@@ -471,7 +474,7 @@ class ShardedBackend(DeltaAwareBackend):
         self.axis = data_axis
         self.mesh = sharded_mesh(self.n_shards, data_axis)
         self.name = f"sharded-{self.name}"   # sharded-<kind | adc-...>
-        self.use_kernel = False       # einsum refine under the mesh
+        self.use_kernel = False       # XLA scans under the mesh
         # failover state (DESIGN.md §16): the health registry is the one
         # mutable truth; masks derived from it are cached on its epoch
         self.n_replicas = int(n_replicas)
@@ -973,7 +976,6 @@ class ShardedBackend(DeltaAwareBackend):
         (nq, S*k') concatenation — the same k'-per-shard collective
         shape as the flat all-gather merge, assembled host-side because
         the traversal itself does not run under the mesh."""
-        from ..kernels.graph_expand import ops as graph_ops
         st = self.store
         Q = np.asarray(Q_sap, np.float32)
         nq = Q.shape[0]
@@ -1000,13 +1002,13 @@ class ShardedBackend(DeltaAwareBackend):
                 db = (self._adc_c8[lo:hi], self._adc_cn[lo:hi])
             else:
                 db = (self._adc_codes_t[:, lo:hi],)
-            cand, cand_d, visited, hops, edges = graph_ops.graph_topk(
+            cand, cand_d, visited, hops, edges = graph_topk(
                 self._g_neigh0_sh[s], self._g_neigh_up_sh[s],
                 self._g_ok[lo:hi], db, qd,
                 jnp.int32(self._g_csrs[s].entry), jnp.int32(ef_eff),
                 kp=kp2, ef_cap=ef_cap, max_hops=max_hops,
                 quant=self.quantization or "f32",
-                oblivious=self.oblivious, use_kernel=False)
+                oblivious=self.oblivious)
             c = np.asarray(cand, np.int32)
             ids_p.append(np.where(c >= 0, c + np.int32(lo), -1))
             d_p.append(np.where(c >= 0, np.asarray(cand_d, np.float32),

@@ -21,19 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = ["quantize_int8", "dequantize_int8", "int8_ring_allreduce",
            "make_int8_allreduce"]
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static mapped-axis size; jax.lax.axis_size is newer than 0.4.x
-    (older jax exposes it via core.axis_frame)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)   # an int on 0.4.x
-    return frame if isinstance(frame, int) else frame.size
 
 
 def quantize_int8(x):
@@ -56,7 +47,7 @@ def int8_ring_allreduce(x, axis_name: str):
     circulating the reduced int8 chunks.  Payload per hop = bytes/4 of the
     f32 equivalent.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)
@@ -102,7 +93,7 @@ def make_int8_allreduce(mesh: Mesh, axis: str = "data"):
         def one(x):
             fn = shard_map(
                 functools.partial(int8_ring_allreduce, axis_name=axis),
-                mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+                mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
             return fn(x)
         return jax.tree.map(one, tree)
 

@@ -9,8 +9,6 @@ Pins the subsystem's contracts:
     parity oracle the batched filter is measured against;
   * the ADC-quantized variant keeps recall; the oblivious variant is
     bit-identical to the perf variant with CONSTANT hop/edge counts;
-  * the Pallas frontier kernel (interpret mode off-TPU) matches the
-    XLA walk;
   * mutations through the delta store: tombstones never surface, new
     rows are reachable before compaction, and the steady state is
     recompile-free on both schedulers;
@@ -31,8 +29,7 @@ from repro.api import IndexSpec, PlacementSpec
 from repro.core import dcpe, ppanns
 from repro.core.hnsw import HNSW
 from repro.data import synth
-from repro.graph import CSRGraph, GraphFilter, beam_plan
-from repro.kernels.graph_expand import ops as graph_ops
+from repro.graph import CSRGraph, GraphFilter
 from repro.serving.runtime import Collection
 from repro.serving.runtime.telemetry import jit_cache_size
 from repro.serving.search_engine import (HNSWGraphFilter, SearchStats,
@@ -110,7 +107,7 @@ def test_batched_filter_matches_host_walk_oracle(setup):
     ds, server, Q, T = setup
     C_sap, C_dce = server.db.C_sap, server.db.C_dce
     eng_g = SecureSearchEngine(
-        C_sap, C_dce, backend=GraphFilter(server.db.index, use_kernel=False))
+        C_sap, C_dce, backend=GraphFilter(server.db.index))
     eng_h = SecureSearchEngine(
         C_sap, C_dce, backend=HNSWGraphFilter(server.db.index))
     with pytest.warns(DeprecationWarning, match="parity oracle"):
@@ -126,7 +123,7 @@ def test_batched_matches_per_query(setup):
     ds, server, Q, T = setup
     eng = SecureSearchEngine(
         server.db.C_sap, server.db.C_dce,
-        backend=GraphFilter(server.db.index, use_kernel=False))
+        backend=GraphFilter(server.db.index))
     whole, _ = eng.search_batch(Q, T, K, ratio_k=8, ef_search=128)
     for i in range(len(Q)):
         one, _ = eng.search_batch(Q[i:i + 1], T[i:i + 1], K, ratio_k=8,
@@ -136,7 +133,7 @@ def test_batched_matches_per_query(setup):
 
 def test_int8_quantized_graph_recall(setup):
     ds, server, Q, T = setup
-    gf = GraphFilter(server.db.index, quantization="int8", use_kernel=False)
+    gf = GraphFilter(server.db.index, quantization="int8")
     eng = SecureSearchEngine(server.db.C_sap, server.db.C_dce, backend=gf)
     ids, st = eng.search_batch(Q, T, K, ratio_k=8, ef_search=128)
     assert st.backend == "adc-graph-int8"
@@ -147,8 +144,8 @@ def test_int8_quantized_graph_recall(setup):
 
 def test_oblivious_bit_identical_with_constant_accounting(setup):
     ds, server, Q, T = setup
-    perf = GraphFilter(server.db.index, use_kernel=False)
-    obl = GraphFilter(server.db.index, use_kernel=False, oblivious=True)
+    perf = GraphFilter(server.db.index)
+    obl = GraphFilter(server.db.index, oblivious=True)
     perf.attach(server.db.C_sap)
     obl.attach(server.db.C_sap)
 
@@ -166,22 +163,6 @@ def test_oblivious_bit_identical_with_constant_accounting(setup):
     tr = obl.last_scan_trace
     assert tr.dtype == np.bool_ and tr.shape[0] == 4
     assert 0 < tr.sum() < tr.size
-
-
-def test_pallas_kernel_interpret_matches_xla(setup):
-    ds, server, Q, T = setup
-    gf = GraphFilter(server.db.index, use_kernel=False)
-    gf.attach(server.db.C_sap)
-    kp = 32
-    ef_eff, ef_cap, max_hops = beam_plan(kp, 64)
-    args = (gf._neigh0, gf._neigh_up, gf._ok, gf._db,
-            gf._query_operand(np.asarray(Q[:4], np.float32)),
-            np.int32(gf.csr.entry), np.int32(ef_eff))
-    kw = dict(kp=kp, ef_cap=ef_cap, max_hops=max_hops, quant="f32")
-    c_xla, *_ = graph_ops.graph_topk(*args, use_kernel=False, **kw)
-    c_pal, *_ = graph_ops.graph_topk(*args, use_kernel=True, interpret=True,
-                                     **kw)
-    np.testing.assert_array_equal(np.asarray(c_xla), np.asarray(c_pal))
 
 
 # ---------------------------------------------------------------------------

@@ -173,3 +173,26 @@ def test_underfilled_candidates_use_sentinel_not_id_zero():
     ids3 = dist.query_batch(cq[None], tq[None], k)
     assert ids3.shape == (1, k) and (ids3[0, 6:] == -1).all()
     np.testing.assert_array_equal(ids3[0, :6], ids[:6])
+
+
+def test_pool_scan_steps_bound_the_gather(monkeypatch):
+    """The IVF pool scan gathers a few queries' rows per step when a
+    whole batch's gather would pass POOL_GATHER_BYTES; the distances
+    and the top-k' they select are those of the one-step scan."""
+    import jax.numpy as jnp
+
+    from repro.serving import search_engine as se
+
+    rng = np.random.default_rng(3)
+    C = jnp.asarray(rng.standard_normal((500, 16)), jnp.float32)
+    Q = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
+    cand = jnp.asarray(rng.integers(0, 500, (8, 128)), jnp.int32)
+    valid = jnp.asarray(rng.random((8, 128)) < 0.9)
+    whole = np.asarray(se.pool_dists(C, Q, cand, valid))
+    # room for two queries' rows per step: four steps over the batch
+    monkeypatch.setattr(se, "POOL_GATHER_BYTES", 2 * 128 * 16 * 4)
+    stepped = np.asarray(se.pool_dists(C, Q, cand, valid))
+    np.testing.assert_allclose(stepped, whole, rtol=1e-6)
+    assert np.array_equal(np.isinf(stepped), ~np.asarray(valid))
+    np.testing.assert_array_equal(np.argsort(stepped, axis=1)[:, :20],
+                                  np.argsort(whole, axis=1)[:, :20])

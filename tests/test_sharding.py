@@ -103,7 +103,7 @@ def test_int8_ring_allreduce_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.sharding.compression import int8_ring_allreduce
         import functools
         from repro.launch.mesh import make_mesh
@@ -113,7 +113,7 @@ def test_int8_ring_allreduce_subprocess():
         ring = shard_map(functools.partial(
             int8_ring_allreduce, axis_name="data"), mesh=mesh,
             in_specs=P("data", None), out_specs=P("data", None),
-            check_rep=False)
+            check_vma=False)
         got = np.asarray(ring(x))
         want = np.asarray(x).sum(0, keepdims=True).repeat(4, 0)
         err = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)

@@ -1,18 +1,13 @@
-"""Kernel/stage profiling suite (EXPERIMENTS.md §Perf, DESIGN.md §13).
+"""Stage profiling suite (EXPERIMENTS.md §Perf, DESIGN.md §13).
 
 Per backend cell (flat f32, int8, pq8) and corpus size, the engine runs
-under BOTH observability layers at once:
-
-  * a `TraceRecorder` ambient span, so the engine's own `filter` /
-    `refine` child spans time the two stages and carry the measured
-    `bytes_scanned` / `comparisons` attributes;
-  * `profile_kernels()`, so the instrumented Pallas/XLA kernel entry
-    points (`l2_topk.knn`, `adc_topk.*`) report block-until-ready-fenced
-    per-call device time and bytes touched at the op level.
-
-The two views must agree: the kernel time is attributed WITHIN the
-filter span.  Writes `BENCH_profile.json` at the repo root (the
-profiling trajectory record) plus the harness's results-dir copy.
+under a `TraceRecorder` ambient span, so the engine's own `filter` /
+`refine` child spans time the two stages and carry the measured
+`bytes_scanned` / `comparisons` attributes.  Writes `BENCH_profile.json`
+at the repo root (the profiling trajectory record) plus the harness's
+results-dir copy.  Device time per kernel comes from a `jax.profiler`
+capture, in which the same spans appear on the host threads (DESIGN.md
+§13).
 
   PYTHONPATH=src python -m benchmarks.bench_profile --smoke
 
@@ -34,7 +29,7 @@ import numpy as np
 
 from repro.core import dcpe, ppanns
 from repro.data import synth
-from repro.obs import Observability, TraceRecorder, profile_kernels
+from repro.obs import Observability, TraceRecorder
 from repro.serving.runtime import Collection
 from repro.serving.search_engine import SecureSearchEngine
 
@@ -64,8 +59,8 @@ def _setup(n: int, d: int, nq: int, seed: int = 0):
 
 def _profile_cell(C_sap, C_dce, Q, T, *, quantization: str | None,
                   seed: int, repeats: int):
-    """One (backend, n) cell: span-level filter/refine seconds + bytes
-    and kernel-level op seconds + bytes, averaged over `repeats` calls."""
+    """One (backend, n) cell: span-level filter/refine seconds + bytes,
+    averaged over `repeats` calls."""
     kw = dict(seed=seed) if quantization is not None else {}
     if quantization == "pq8":
         kw.update(pq_m=32, refine_ratio=8.0)
@@ -73,25 +68,20 @@ def _profile_cell(C_sap, C_dce, Q, T, *, quantization: str | None,
                              quantization=quantization, **kw)
     eng.search_batch(Q, T, K, ratio_k=RATIO_K)       # warmup/compile
     rec = TraceRecorder()
-    with profile_kernels() as prof:
-        for i in range(repeats):
-            with rec.span("profile", f"cell:{i}"):
-                eng.search_batch(Q, T, K, ratio_k=RATIO_K)
+    for i in range(repeats):
+        with rec.span("profile", f"cell:{i}"):
+            eng.search_batch(Q, T, K, ratio_k=RATIO_K)
     stages = {"filter": [], "refine": []}
     attrs = {}
     for sp in rec.spans():
         if sp.name in stages:
             stages[sp.name].append(sp.duration)
             attrs[sp.name] = sp.attrs
-    kernel_prefix = "adc_topk" if quantization else "l2_topk"
     return {
         "filter_s": sum(stages["filter"]) / repeats,
         "refine_s": sum(stages["refine"]) / repeats,
         "filter_bytes": int(attrs["filter"].get("bytes_scanned", 0)),
         "refine_comparisons": int(attrs["refine"].get("comparisons", 0)),
-        "kernel": kernel_prefix,
-        "kernel_s": prof.total_seconds(kernel_prefix) / repeats,
-        "kernel_bytes": prof.total_bytes(kernel_prefix) // max(repeats, 1),
     }
 
 
@@ -108,10 +98,7 @@ def run(sizes=(10_000, 100_000), d: int = 128, nq: int = NQ,
             rows.append(row(
                 f"profile/n={n}/flat/{label}/filter",
                 1e6 * c["filter_s"] / nq,
-                f"bytes_scanned={c['filter_bytes']} "
-                f"kernel={c['kernel']} "
-                f"kernel_us_per_call={1e6 * c['kernel_s'] / nq:.1f} "
-                f"kernel_bytes={c['kernel_bytes']}"))
+                f"bytes_scanned={c['filter_bytes']}"))
             rows.append(row(
                 f"profile/n={n}/flat/{label}/refine",
                 1e6 * c["refine_s"] / nq,

@@ -192,16 +192,15 @@ def check_quantization_surface(api) -> list[str]:
 # surface contract (DESIGN.md §13).
 REQUIRED_OBS_EXPORTS = {
     "Observability", "TraceRecorder", "NullRecorder", "NULL_RECORDER",
-    "Span", "child_span", "child_complete", "current",
+    "Span", "child_span", "child_complete", "current", "to_host",
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
-    "KernelProfiler", "profile_kernels", "instrument", "active_profiler",
     "start_metrics_server",
 }
 
 
 def check_obs_surface(api) -> list[str]:
     """The observability surface contract (DESIGN.md §13): repro.obs
-    exports the tracing/metrics/profiling entry points, the service
+    exports the tracing/metrics entry points, the service
     exposes the export methods, and SearchRequest carries trace_id over
     the wire."""
     import dataclasses

@@ -30,6 +30,7 @@ import numpy as np
 
 from ..core import adc
 from ..core.hnsw import HNSW
+from ..obs.trace import to_host
 from .csr import CSRGraph
 from .traverse import beam_plan, graph_topk
 
@@ -133,14 +134,14 @@ class GraphFilter:
             jnp.int32(ef_eff), kp=kp2, ef_cap=ef_cap,
             max_hops=max_hops, quant=self.quant,
             oblivious=self.oblivious)
-        cand = np.asarray(cand, np.int32)
+        cand = to_host(cand, "candidates", np.int32)
         valid = cand >= 0
         cand = np.where(valid, cand, 0)
-        n_edges = int(np.asarray(edges).sum())
-        self.last_n_hops = int(np.asarray(hops).sum())
+        n_edges = int(to_host(edges, "edges").sum())
+        self.last_n_hops = int(to_host(hops, "hops").sum())
         self.last_n_edges_scanned = n_edges
         # every scored edge reads one row (f32) or one code row (ADC),
         # plus the entry-point read per query
         self.last_filter_bytes = (n_edges + nq) * self._row_bytes
-        self.last_scan_trace = np.asarray(visited)
+        self.last_scan_trace = to_host(visited, "visited")
         return cand, valid, n_edges + nq

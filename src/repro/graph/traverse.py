@@ -287,10 +287,3 @@ def traverse(neigh0, neigh_up, ok, db, qd, entry, ef, *, kp: int,
 graph_topk = jax.jit(
     traverse,
     static_argnames=("kp", "ef_cap", "max_hops", "quant", "oblivious"))
-
-# Opt-in kernel profiling (repro.obs, DESIGN.md §13): strict
-# passthrough unless a KernelProfiler is active; `_cache_size` is
-# preserved for the recompile audit.
-from ..obs.profiler import instrument as _instrument  # noqa: E402
-
-graph_topk = _instrument("graph.graph_topk", graph_topk)

@@ -229,18 +229,3 @@ def pq_oblivious_scan(codes_t, lut, member, kp: int):
     _, pos = jax.lax.top_k(-d, kp)
     return (pos.astype(jnp.int32),
             jnp.take_along_axis(member, pos, axis=1))
-
-
-# Opt-in kernel profiling (repro.obs, DESIGN.md §13): strict
-# passthrough unless a KernelProfiler is active; `_cache_size` is
-# preserved for the recompile audit.
-from ...obs.profiler import instrument as _instrument  # noqa: E402
-
-sq_knn = _instrument("adc_topk.sq_knn", sq_knn)
-pq_knn = _instrument("adc_topk.pq_knn", pq_knn)
-sq_pool_scan = _instrument("adc_topk.sq_pool_scan", sq_pool_scan)
-pq_pool_scan = _instrument("adc_topk.pq_pool_scan", pq_pool_scan)
-sq_oblivious_scan = _instrument("adc_topk.sq_oblivious_scan",
-                                sq_oblivious_scan)
-pq_oblivious_scan = _instrument("adc_topk.pq_oblivious_scan",
-                                pq_oblivious_scan)

@@ -84,16 +84,3 @@ def batched_top_k_by_wins(
     k = min(k, n)
     _, idx = jax.lax.top_k(wins, k)
     return idx.astype(jnp.int32)
-
-
-# Opt-in kernel profiling (repro.obs, DESIGN.md §13): strict
-# passthrough unless a KernelProfiler is active.  batched_top_k_by_wins
-# is also traced inside jitted engine code (refine_candidates, the
-# sharded refine) — the wrapper detects tracer arguments and records
-# only genuine host-initiated calls.  `_cache_size` is preserved for
-# the recompile audit.
-from ...obs.profiler import instrument as _instrument  # noqa: E402
-
-top_k_by_wins = _instrument("dce_comp.top_k_by_wins", top_k_by_wins)
-batched_top_k_by_wins = _instrument("dce_comp.batched_top_k_by_wins",
-                                    batched_top_k_by_wins)

@@ -66,13 +66,3 @@ def knn(
 
     best_d, best_i = running_topk_scan(dist_fn, n_pad, nq, k, chunk)
     return best_d, best_i.astype(jnp.int32)
-
-
-# Opt-in kernel profiling (repro.obs, DESIGN.md §13): a strict
-# passthrough unless a KernelProfiler is active, fencing each call with
-# block_until_ready and recording device time + bytes touched.  The
-# wrapper preserves `_cache_size` for the recompile audit
-# (serving.runtime.telemetry.jit_cache_size).
-from ...obs.profiler import instrument as _instrument  # noqa: E402
-
-knn = _instrument("l2_topk.knn", knn)

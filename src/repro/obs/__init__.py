@@ -1,17 +1,16 @@
 """repro.obs — observability for the secure serving stack (DESIGN.md §13).
 
-Three independent pieces, composable or standalone:
+Two independent pieces, composable or standalone:
 
-  * `TraceRecorder` (trace.py): structured per-request span trees,
-    deterministic under `VirtualClock`, exported as Chrome-trace JSON
-    or a structured event log.
+  * `TraceRecorder` (trace.py): structured per-request and per-batch
+    span trees, deterministic under `VirtualClock`, exported as
+    Chrome-trace JSON or a structured event log.  Live spans are
+    mirrored as `jax.profiler` annotations, and `to_host` puts every
+    device->host read of the search path under a `sync` span.
   * `MetricsRegistry` (metrics.py): counters/gauges/histograms with
     Prometheus text exposition.
-  * `KernelProfiler` / `profile_kernels` (profiler.py): opt-in
-    block-until-ready-fenced timing of the Pallas/XLA kernel entry
-    points.
 
-`Observability` bundles all three with one clock, which is what
+`Observability` bundles both with one clock, which is what
 `SecureAnnService(obs=...)` threads through the runtime.  Everything
 is disabled-by-default at the call sites: a collection with no tracer
 and no metrics attached records nothing and pays (nearly) nothing.
@@ -24,23 +23,20 @@ import threading
 
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       DEFAULT_LATENCY_BUCKETS)
-from .profiler import (KernelProfiler, active_profiler, instrument,
-                       profile_kernels)
 from .trace import (NULL_RECORDER, NullRecorder, Span, TraceRecorder,
-                    child_complete, child_span, current)
+                    child_complete, child_span, current, to_host)
 
 __all__ = [
     "Observability", "start_metrics_server",
     "TraceRecorder", "NullRecorder", "NULL_RECORDER", "Span",
-    "child_span", "child_complete", "current",
+    "child_span", "child_complete", "current", "to_host",
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "DEFAULT_LATENCY_BUCKETS",
-    "KernelProfiler", "profile_kernels", "instrument", "active_profiler",
 ]
 
 
 class Observability:
-    """One recorder + one registry + one profiler sharing one clock.
+    """One recorder + one registry sharing one clock.
 
     clock: the runtime `Clock` the schedulers run on (None = wall
     time).  Using the same instance keeps span timestamps, telemetry
@@ -52,7 +48,6 @@ class Observability:
         self.recorder = TraceRecorder(clock=clock,
                                       capacity=trace_capacity)
         self.metrics = MetricsRegistry()
-        self.profiler = KernelProfiler()
 
     # convenience passthroughs -------------------------------------
 

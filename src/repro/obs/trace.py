@@ -3,9 +3,19 @@
 One `TraceRecorder` per service (or per collection): a clock-injected,
 ring-buffered, thread-safe span store.  Spans form per-trace trees —
 one trace per request (`request` root with `queue`/`flush`|`slot`/
-`emit` children), one trace per batched engine call (`flush`/`step`
-root with `filter`/`refine` children, linked to the requests that rode
-it by a `batch` attribute), one trace per ingest operation.
+`emit` children), one trace per batched engine call (a `batch` root
+with `collect`/`assemble`/`flush`/`deliver` children under the
+micro-batcher, a bare `batch` root for a direct batch request, a `step`
+root under the slot loop; the engine's `filter`/`refine` spans nest
+inside, each device->host read under them a `sync` span; linked to the
+requests that rode it by a `batch` attribute), one trace per ingest
+operation.
+
+Every span opened as a context manager (`TraceRecorder.span`,
+`child_span`, `to_host`) also enters a `jax.profiler.TraceAnnotation`
+of the same name on its thread, so a `jax.profiler` capture shows the
+program's spans on the host threads beside the device ops, on the
+capture's own clock.  Retroactive spans (`add_span`) are not mirrored.
 
 Three properties the rest of the repo depends on:
 
@@ -14,9 +24,10 @@ Three properties the rest of the repo depends on:
     schedulers run on, so tests assert exact span trees (structure,
     attributes, and virtual timestamps) for scripted interleavings.
   * **Near-free when disabled** — nothing in the hot path allocates or
-    locks when no recorder is attached: `child_span()` is a single
-    contextvar read returning a shared no-op span, and the schedulers
-    guard every recording call on `tracer is not None`.
+    locks when no recorder is attached: `child_span()` and `to_host()`
+    cost a single contextvar read (the former returns a shared no-op
+    span), the schedulers guard every recording call on `tracer is not
+    None`, and no profiler annotation is entered.
   * **No plaintext leakage** — spans carry ids, counts, byte totals,
     and backend names.  They never carry query or database ciphertext
     material (let alone plaintexts); the trace of a search is exactly
@@ -35,8 +46,11 @@ import threading
 import time
 from collections import deque
 
+import numpy as np
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Span", "TraceRecorder", "NullRecorder", "NULL_RECORDER",
-           "child_span", "child_complete", "current"]
+           "child_span", "child_complete", "current", "to_host"]
 
 
 class Span:
@@ -45,7 +59,7 @@ class Span:
     pops the ambient-context stack on exit)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t_start",
-                 "t_end", "attrs", "_recorder", "_token")
+                 "t_end", "attrs", "_recorder", "_token", "_annotation")
 
     def __init__(self, name: str, trace_id: str, span_id: int,
                  parent_id: int | None, t_start: float,
@@ -59,6 +73,7 @@ class Span:
         self.attrs = dict(attrs or {})
         self._recorder = None
         self._token = None
+        self._annotation = None
 
     def set(self, **attrs):
         """Attach attributes after the fact (e.g. counters only known
@@ -134,6 +149,21 @@ def child_span(name: str, **attrs):
     recorder, parent = ctx
     return recorder.span(name, trace_id=parent.trace_id, parent=parent,
                          **attrs)
+
+
+def to_host(x, what: str, dtype=None) -> np.ndarray:
+    """`np.asarray(x, dtype)` of a device array, read under a `sync`
+    child span (attribute `what`) of the ambient context: every
+    device->host read on the search path goes through here, so a trace
+    separates the time the host waits on the device from its own work.
+    Without an ambient context it costs one contextvar read."""
+    ctx = _CTX.get()
+    if ctx is None:
+        return np.asarray(x, dtype)
+    recorder, parent = ctx
+    with recorder.span("sync", trace_id=parent.trace_id, parent=parent,
+                       what=what):
+        return np.asarray(x, dtype)
 
 
 def child_complete(name: str, t_start: float | None = None,
@@ -214,18 +244,25 @@ class TraceRecorder:
              **attrs) -> Span:
         """Context-manager span: opens now, closes (and records) on
         exit, and publishes itself as the ambient context so nested
-        `child_span` calls attach underneath."""
+        `child_span` calls attach underneath.  It also enters a
+        `jax.profiler.TraceAnnotation` of the same name on this thread
+        until it closes (on the same thread)."""
         sp = self.start_span(name, trace_id, parent=parent, **attrs)
         sp._recorder = self
         sp._token = _CTX.set((self, sp))
+        sp._annotation = TraceAnnotation(name)
+        sp._annotation.__enter__()
         return sp
 
     def _close_cm_span(self, span: Span):
+        self.end_span(span)
+        if span._annotation is not None:
+            span._annotation.__exit__(None, None, None)
+            span._annotation = None
         if span._token is not None:
             _CTX.reset(span._token)
             span._token = None
         span._recorder = None
-        self.end_span(span)
 
     # ---------------------------------------------------------- reading
 

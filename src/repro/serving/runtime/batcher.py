@@ -38,6 +38,7 @@ from concurrent.futures import TimeoutError as FutureTimeoutError
 import numpy as np
 
 from ...kernels.common import next_bucket
+from ...obs.trace import NULL_RECORDER, child_span
 from .clock import Clock, SystemClock
 
 __all__ = ["Scheduler", "MicroBatcher", "QueueFullError", "batch_buckets",
@@ -435,57 +436,68 @@ class MicroBatcher(Scheduler):
                     self.clock.wait(self._cv, None)
                 if not self._pending:
                     return                       # closed and drained
-                head = self._pending[0]
-                deadline = head.t_enq + self.max_wait_s
-                while (not self._closed
-                       and self._n_matching_locked(head.group)
-                       < self.max_batch):
-                    remaining = deadline - self.clock.now()
-                    if remaining <= 0:
-                        break
-                    self.clock.wait(self._cv, remaining)
-                batch = self._take_group_locked(head.group)
-                depth = len(self._pending)
-            if batch:                            # all discarded mid-wait?
-                self._flush(batch, depth)
+                # the batch trace opens when the worker sees its head
+                # request; `collect` is the wait for size or deadline
+                root = self._open_batch()
+                with child_span("collect"):
+                    head = self._pending[0]
+                    deadline = head.t_enq + self.max_wait_s
+                    while (not self._closed
+                           and self._n_matching_locked(head.group)
+                           < self.max_batch):
+                        remaining = deadline - self.clock.now()
+                        if remaining <= 0:
+                            break
+                        self.clock.wait(self._cv, remaining)
+                    batch = self._take_group_locked(head.group)
+                    depth = len(self._pending)
+            with root:
+                root.set(n_real=len(batch))
+                if batch:                        # all discarded mid-wait?
+                    self._flush(batch, depth, root)
 
-    def _flush(self, batch: list[_Request], queue_depth: int):
+    def _open_batch(self):
+        """The root `batch` span of the next engine call's trace
+        (`{name}:bN`), ambient until it closes; a no-op span when
+        tracing is off."""
+        if self.tracer is None:
+            return NULL_RECORDER.span("batch", "")
+        tid = f"{self.name}:b{self._batch_seq}"
+        self._batch_seq += 1
+        return self.tracer.span("batch", tid, collection=self.name)
+
+    def _flush(self, batch: list[_Request], queue_depth: int, root):
         """Any failure lands on the batch's futures, never on the
-        scheduler thread — one bad request must not wedge the queue."""
+        scheduler thread — one bad request must not wedge the queue.
+        Under the ambient `root` batch span: `assemble`, `flush` (the
+        engine call, with the engine's filter/refine inside) and
+        `deliver`."""
         k, ratio_k, ef_search = batch[0].group
         B = len(batch)
         tracer = self.tracer
         t_take = self.clock.now()      # queue wait ends, assembly begins
-        batch_tid = ""
         try:
-            bucket = (self.max_batch if self.pad_policy == "full"
-                      else next_bucket(B, minimum=1,
-                                       maximum=self.max_batch))
-            if self.pad_policy == "replicate":
-                pad_q, pad_t = batch[0].Q, batch[0].T
-                n_dummies = 0
-            else:           # dummy rows: zero-content queries that ride
-                pad_q = np.zeros_like(batch[0].Q)     # the batched call
-                pad_t = np.zeros_like(batch[0].T)     # but no future
-                n_dummies = bucket - B
-            Q = np.stack([r.Q for r in batch] + [pad_q] * (bucket - B))
-            T = np.stack([r.T for r in batch] + [pad_t] * (bucket - B))
+            with child_span("assemble"):
+                bucket = (self.max_batch if self.pad_policy == "full"
+                          else next_bucket(B, minimum=1,
+                                           maximum=self.max_batch))
+                if self.pad_policy == "replicate":
+                    pad_q, pad_t = batch[0].Q, batch[0].T
+                    n_dummies = 0
+                else:       # dummy rows: zero-content queries that ride
+                    pad_q = np.zeros_like(batch[0].Q)  # the batched call
+                    pad_t = np.zeros_like(batch[0].T)  # but no future
+                    n_dummies = bucket - B
+                Q = np.stack([r.Q for r in batch] + [pad_q] * (bucket - B))
+                T = np.stack([r.T for r in batch] + [pad_t] * (bucket - B))
             lock = (self.verify_lock if self.verify_parity
                     and self.verify_lock is not None
                     else contextlib.nullcontext())
             with lock:
-                if tracer is not None:
-                    # the batch trace: one "flush" root over the engine
-                    # call; the engine's filter/refine child spans attach
-                    # under it through the ambient context
-                    batch_tid = f"{self.name}:b{self._batch_seq}"
-                    self._batch_seq += 1
-                    bspan = tracer.span(
-                        "flush", batch_tid, collection=self.name,
-                        n_real=B, bucket=int(bucket), k=k)
-                else:
-                    bspan = contextlib.nullcontext()
-                with bspan:
+                # the engine call; its filter/refine child spans attach
+                # under it through the ambient context
+                with child_span("flush", collection=self.name, n_real=B,
+                                bucket=int(bucket), k=k) as fsp:
                     ids, stats = self._run_batch(Q, T, k, ratio_k=ratio_k,
                                                  ef_search=ef_search)
                     stats.n_dummy_queries = n_dummies
@@ -494,7 +506,7 @@ class MicroBatcher(Scheduler):
                     # would inflate p99
                     now = self.clock.now()
                     if tracer is not None:
-                        bspan.set(**_stats_attrs(stats))
+                        fsp.set(**_stats_attrs(stats))
                 if self.verify_parity:           # engine parity, per request
                     for i, r in enumerate(batch):
                         single, _ = self._run_batch(
@@ -507,25 +519,26 @@ class MicroBatcher(Scheduler):
             # is quarantined when its attempts run out (DESIGN.md §16)
             self._retry_failed_batch(batch, exc, batch[0].group)
             return
-        for i, r in enumerate(batch):
-            row = np.asarray(ids[i])
-            self._resolve(r.future,
-                          result=(row, stats) if r.want_stats else row)
-        if tracer is not None:
-            t_emit = self.clock.now()
-            stats_attrs = _stats_attrs(stats)
-            for r in batch:
-                if r.span is None:
-                    continue
-                tracer.add_span("queue", r.trace_id, r.t_enq, t_take,
-                                parent=r.span)
-                tracer.add_span("flush", r.trace_id, t_take, now,
-                                parent=r.span, batch=batch_tid,
-                                n_real=B, backend=stats.backend)
-                tracer.add_span("emit", r.trace_id, now, t_emit,
-                                parent=r.span)
-                tracer.end_span(r.span, **stats_attrs)
-        if self.telemetry is not None:
-            self.telemetry.record_flush(
-                B, [now - r.t_enq for r in batch], stats,
-                queue_depth, shape=Q.shape, n_dummies=n_dummies)
+        with child_span("deliver"):
+            for i, r in enumerate(batch):
+                row = np.asarray(ids[i])
+                self._resolve(r.future,
+                              result=(row, stats) if r.want_stats else row)
+            if tracer is not None:
+                t_emit = self.clock.now()
+                stats_attrs = _stats_attrs(stats)
+                for r in batch:
+                    if r.span is None:
+                        continue
+                    tracer.add_span("queue", r.trace_id, r.t_enq, t_take,
+                                    parent=r.span)
+                    tracer.add_span("flush", r.trace_id, t_take, now,
+                                    parent=r.span, batch=root.trace_id,
+                                    n_real=B, backend=stats.backend)
+                    tracer.add_span("emit", r.trace_id, now, t_emit,
+                                    parent=r.span)
+                    tracer.end_span(r.span, **stats_attrs)
+            if self.telemetry is not None:
+                self.telemetry.record_flush(
+                    B, [now - r.t_enq for r in batch], stats,
+                    queue_depth, shape=Q.shape, n_dummies=n_dummies)

@@ -80,7 +80,9 @@ class Collection:
         # batch/ingest span trees), metrics = repro.obs.MetricsRegistry
         # (cross-collection Prometheus instruments).  Both default off.
         self.tracer = tracer
-        self._ingest_seq = 0
+        # root-span trace ids: {tenant}/{name}:iN (ingest), :dN (direct
+        # batch requests)
+        self._trace_seq = {"i": 0, "d": 0}
         if seed is None:
             # fresh entropy per collection: two tenants must never derive
             # the same key pair just because neither passed a seed
@@ -188,16 +190,18 @@ class Collection:
 
     # ------------------------------------------------------- ingestion
 
-    def _ingest_span(self, op: str):
-        """One trace per ingest operation (DESIGN.md §13): a root span
-        the store's compaction hook attaches under via the ambient
-        context.  A shared no-op span when tracing is off."""
+    def _root_span(self, name: str, seq: str, **attrs):
+        """The root span of a trace of its own (DESIGN.md §13): one per
+        ingest operation (`seq` "i") or direct batch request ("d"),
+        ambient until it closes, so the store's compaction hook or the
+        engine's spans attach under it.  A shared no-op span when
+        tracing is off."""
         if self.tracer is None:
-            return NULL_RECORDER.span(op, "")
-        tid = f"{self.tenant}/{self.name}:i{self._ingest_seq}"
-        self._ingest_seq += 1
+            return NULL_RECORDER.span(name, "")
+        tid = f"{self.tenant}/{self.name}:{seq}{self._trace_seq[seq]}"
+        self._trace_seq[seq] += 1
         return self.tracer.span(
-            op, tid, collection=f"{self.tenant}/{self.name}")
+            name, tid, collection=f"{self.tenant}/{self.name}", **attrs)
 
     def insert(self, P: np.ndarray) -> np.ndarray:
         """Owner-side API: batch-encrypt plaintext vectors (jitted DCPE +
@@ -212,7 +216,7 @@ class Collection:
     def insert_encrypted(self, C_sap: np.ndarray,
                          C_dce: np.ndarray) -> np.ndarray:
         """Server-side API: append pre-encrypted rows (wire format)."""
-        with self._ingest_span("insert") as sp, self._lock:
+        with self._root_span("insert", "i") as sp, self._lock:
             rows = self.store.append(C_sap, C_dce)
             self._backend.on_insert(rows, C_sap)
             compacted = False
@@ -238,7 +242,7 @@ class Collection:
         so a bad id cannot leave the batch half-applied (and the engine
         is re-marked dirty even if a backend hook fails mid-way)."""
         rows = [int(r) for r in np.atleast_1d(np.asarray(ids, np.int64))]
-        with self._ingest_span("delete") as sp, self._lock:
+        with self._root_span("delete", "i") as sp, self._lock:
             sp.set(n_rows=len(rows))
             seen: set[int] = set()
             for row in rows:
@@ -262,7 +266,7 @@ class Collection:
         return len(rows)
 
     def compact(self):
-        with self._ingest_span("compact"), self._lock:
+        with self._root_span("compact", "i"), self._lock:
             self.store.compact()
             self._refresh_engine()
             # an EXPLICIT compact is an acknowledged state transition
@@ -291,7 +295,7 @@ class Collection:
             alive = np.ones(n, bool)
         if n_main < 0:
             n_main = n            # an uploaded corpus is all main region
-        with self._ingest_span("load_snapshot") as sp, self._lock:
+        with self._root_span("load_snapshot", "i") as sp, self._lock:
             sp.set(n_rows=n)
             self.store.restore(C_sap, C_dce, alive, n_main, main_gen)
             if self._backend.kind in ("hnsw", "graph"):
@@ -463,8 +467,12 @@ class Collection:
                            ef_search=ef_search).result(timeout=timeout)
 
     def search_batch(self, Q, T, k, **kw):
-        """Bulk client path: straight to the engine (still locked)."""
-        return self._run_batch(Q, T, k, **kw)
+        """Bulk client path: straight to the engine (still locked),
+        under a `batch` root span (`{tenant}/{name}:dN`, DESIGN.md §13)
+        opened once the lock is held, so it times this call alone."""
+        with self._lock, self._root_span(
+                "batch", "d", n_real=int(np.atleast_2d(Q).shape[0])):
+            return self._run_batch(Q, T, k, **kw)
 
     def warmup(self, k: int = 10, *, ratio_k: float = 8.0,
                ef_search: int = 96):

@@ -42,7 +42,7 @@ from ...graph.traverse import beam_plan, graph_topk
 from ...kernels.adc_topk import ops as adc_ops
 from ...kernels.common import next_bucket
 from ...kernels.l2_topk import ops as l2_ops
-from ...obs.trace import child_complete
+from ...obs.trace import child_complete, to_host
 from .. import search_engine as se
 
 __all__ = ["MutableEncryptedStore", "DeltaAwareBackend", "SENTINEL"]
@@ -672,7 +672,7 @@ class DeltaAwareBackend:
             _, idx = adc_ops.pq_knn(jnp.asarray(lut), self._adc_codes_t,
                                     kp2, ok=self._adc_ok,
                                     use_kernel=self._use_pallas())
-        cand = np.asarray(idx, np.int32)
+        cand = to_host(idx, "candidates", np.int32)
         safe, valid = self._mask_alive(cand, np.ones(cand.shape, bool))
         self.last_filter_bytes = self._adc_code_bytes(bucket)
         # rows present (incl. tombstones), matching the f32 flat path's
@@ -705,8 +705,9 @@ class DeltaAwareBackend:
                 ids, vout = adc_ops.pq_oblivious_scan(
                     self._adc_codes_t, jnp.asarray(lut),
                     jnp.asarray(member), min(kp2, bucket))
-            ids, vout = self._mask_alive(np.asarray(ids, np.int32),
-                                         np.asarray(vout))
+            ids, vout = self._mask_alive(
+                to_host(ids, "candidates", np.int32),
+                to_host(vout, "valid"))
             evals = nq * bucket + nq * self.ivf.centroids.shape[0]
             self.last_filter_bytes = (self._adc_code_bytes(bucket)
                                       + self.ivf.centroids.nbytes)
@@ -728,7 +729,8 @@ class DeltaAwareBackend:
         self.last_filter_bytes = (
             self._adc_code_bytes(sum(p.size for p in pools))
             + self.ivf.centroids.nbytes)
-        return np.asarray(ids), np.asarray(vout), evals
+        return (to_host(ids, "candidates"), to_host(vout, "valid"),
+                evals)
 
     def _candidates_flat(self, Q_sap: np.ndarray, kp: int):
         st = self.store
@@ -740,20 +742,20 @@ class DeltaAwareBackend:
             dist, idx = l2_ops.knn(Qd, self._C_main, min(kp, n_main),
                                    chunk=min(4096, n_main),
                                    use_kernel=self.use_kernel)
-            cand = np.asarray(idx, np.int32)
+            cand = to_host(idx, "candidates", np.int32)
             safe, valid = self._mask_alive(cand,
                                            np.ones(cand.shape, bool))
-            parts.append((np.asarray(dist), safe, valid))
+            parts.append((to_host(dist, "distances"), safe, valid))
             evals += nq * n_main
         if self._C_delta is not None:
             bucket = int(self._C_delta.shape[0])
             dist, idx = l2_ops.knn(Qd, self._C_delta, min(kp, bucket),
                                    chunk=bucket, use_kernel=self.use_kernel)
-            raw = np.asarray(idx, np.int32)
+            raw = to_host(idx, "candidates", np.int32)
             in_delta = raw < self._delta_n
             cand = raw + np.int32(self._delta_base)
             safe, valid = self._mask_alive(cand, in_delta)
-            parts.append((np.asarray(dist), safe, valid))
+            parts.append((to_host(dist, "distances"), safe, valid))
             evals += nq * self._delta_n
         self.last_filter_bytes = st.d * 4 * (
             (int(self._C_main.shape[0]) if self._C_main is not None else 0)
@@ -822,15 +824,15 @@ class DeltaAwareBackend:
             qd, jnp.int32(self._csr.entry), jnp.int32(ef_eff),
             kp=kp2, ef_cap=ef_cap, max_hops=max_hops,
             quant=self.quantization or "f32", oblivious=self.oblivious)
-        safe, valid = self._mask_alive(np.asarray(cand, np.int32),
-                                       np.asarray(cand) >= 0)
-        n_edges = int(np.asarray(edges).sum())
-        self.last_n_hops = int(np.asarray(hops).sum())
+        cand = to_host(cand, "candidates", np.int32)
+        safe, valid = self._mask_alive(cand, cand >= 0)
+        n_edges = int(to_host(edges, "edges").sum())
+        self.last_n_hops = int(to_host(hops, "hops").sum())
         self.last_n_edges_scanned = n_edges
         row_bytes = (st.d * 4 if self.quantization is None
                      else self.adc_codebook.code_bytes_per_vector())
         self.last_filter_bytes = (n_edges + nq) * row_bytes
-        self.last_scan_trace = np.asarray(visited)
+        self.last_scan_trace = to_host(visited, "visited")
         return safe, valid, n_edges + nq
 
     def _candidates_hnsw(self, Q_sap: np.ndarray, kp: int, ef_search: int):

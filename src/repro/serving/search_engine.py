@@ -44,7 +44,7 @@ from ..kernels.adc_topk import ops as adc_ops
 from ..kernels.common import HIGHEST, next_bucket
 from ..kernels.dce_comp import ops as dce_ops
 from ..kernels.l2_topk import ops as l2_ops
-from ..obs.trace import child_span
+from ..obs.trace import child_span, to_host
 
 __all__ = ["SearchStats", "SecureSearchEngine", "FlatScanFilter",
            "IVFScanFilter", "HNSWGraphFilter", "ADCFilter",
@@ -200,7 +200,7 @@ def scan_ivf_pools(C_dev, Q_sap: np.ndarray, pools, kp: int,
     ids, vout = _masked_pruned_scan(
         C_dev, jnp.asarray(np.asarray(Q_sap, np.float32)),
         jnp.asarray(cand), jnp.asarray(valid), kp)
-    return np.asarray(ids), np.asarray(vout)
+    return to_host(ids, "candidates"), to_host(vout, "valid")
 
 
 @functools.partial(jax.jit, static_argnames=("kp",))
@@ -248,7 +248,7 @@ def scan_ivf_oblivious(C_dev, Q_sap: np.ndarray, pools, kp: int,
     ids, vout = _masked_full_scan(
         C_dev, jnp.asarray(np.asarray(Q_sap, np.float32)),
         jnp.asarray(member), kp)
-    return np.asarray(ids), np.asarray(vout)
+    return to_host(ids, "candidates"), to_host(vout, "valid")
 
 
 def traverse_graph_candidates(index: HNSW, Q_sap: np.ndarray, kp: int,
@@ -296,7 +296,7 @@ class FlatScanFilter:
         _, idx = l2_ops.knn(jnp.asarray(Q_sap, jnp.float32), self._C,
                             min(kp, n), chunk=min(self.chunk, n),
                             use_kernel=self.use_kernel)
-        cand = np.asarray(idx, np.int32)
+        cand = to_host(idx, "candidates", np.int32)
         valid = np.ones(cand.shape, bool)
         self.last_filter_bytes = int(self._C.size) * 4
         return cand, valid, Q_sap.shape[0] * n
@@ -465,7 +465,7 @@ class ADCFilter:
                 _, idx = adc_ops.pq_knn(jnp.asarray(lut), self._codes_t,
                                         kp2,
                                         use_kernel=self._use_pallas())
-            cand = np.asarray(idx, np.int32)
+            cand = to_host(idx, "candidates", np.int32)
             # -1 marks slots beyond the valid-row count (kp' > n); the
             # refine sees them masked, never a wrapped gather index
             valid = cand >= 0
@@ -490,7 +490,8 @@ class ADCFilter:
         self.last_filter_bytes = (sum(p.size for p in pools)
                                   * self._code_bytes()
                                   + self.ivf.centroids.nbytes)
-        return np.asarray(ids), np.asarray(vout), evals
+        return (to_host(ids, "candidates"), to_host(vout, "valid"),
+                evals)
 
 
 _BACKENDS = {"flat": FlatScanFilter, "ivf": IVFScanFilter}
@@ -617,7 +618,7 @@ class SecureSearchEngine:
                     out = refine_candidates(
                         self._C_dce_dev, jnp.asarray(cand), jnp.asarray(T_q),
                         jnp.asarray(valid), k, self.use_kernel)
-                ids = np.asarray(out, np.int64)
+                ids = to_host(out, "ids", np.int64)
                 nv = valid.sum(axis=1)
                 ncmp = int((nv * (nv - 1)).sum())
             elif refine == "none":          # filter-only baseline

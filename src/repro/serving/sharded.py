@@ -77,7 +77,7 @@ from ..kernels.adc_topk.ops import INT_BIG
 from ..kernels.common import HIGHEST, next_bucket
 from ..kernels.dce_comp import ops as dce_ops
 from ..launch.mesh import make_mesh
-from ..obs.trace import child_complete, current as obs_current
+from ..obs.trace import child_complete, current as obs_current, to_host
 from ..resilience.health import ShardHealthRegistry
 from .runtime.ingest import SENTINEL, DeltaAwareBackend
 from .search_engine import layout_pools, pool_dists, pool_membership
@@ -859,7 +859,7 @@ class ShardedBackend(DeltaAwareBackend):
             cand = _sharded_pq_topk(
                 self._adc_codes_t, ok, jnp.asarray(lut),
                 mesh=self.mesh, axis=self.axis, kp=kp_eff)
-        cand = np.asarray(cand, np.int32)
+        cand = to_host(cand, "candidates", np.int32)
         safe, valid = self._mask_alive(cand, np.ones(cand.shape, bool))
         self.last_filter_bytes = self._adc_code_bytes(bucket)
         return safe, valid, nq * st.n_total     # same accounting as the
@@ -889,7 +889,7 @@ class ShardedBackend(DeltaAwareBackend):
                     self._adc_codes_t, jnp.asarray(lut),
                     jnp.asarray(member), mesh=self.mesh, axis=self.axis,
                     kp=kp_eff)
-            ids = np.asarray(ids, np.int32)
+            ids = to_host(ids, "candidates", np.int32)
             # validity = host-side membership lookup at the merged ids
             vout = member[np.arange(nq)[:, None], np.clip(ids, 0, bucket - 1)]
             ids, vout = self._mask_alive(ids, vout)
@@ -915,7 +915,8 @@ class ShardedBackend(DeltaAwareBackend):
         self.last_filter_bytes = (
             self._adc_code_bytes(sum(p.size for p in pools))
             + self.ivf.centroids.nbytes)
-        return np.asarray(ids), np.asarray(vout), evals
+        return (to_host(ids, "candidates"), to_host(vout, "valid"),
+                evals)
 
     def _candidates_flat(self, Q_sap: np.ndarray, kp: int):
         st = self.store
@@ -930,7 +931,7 @@ class ShardedBackend(DeltaAwareBackend):
         else:
             cand = _sharded_flat_topk(self._C_all, Qd, mesh=self.mesh,
                                       axis=self.axis, kp=kp_eff)
-        cand = np.asarray(cand, np.int32)
+        cand = to_host(cand, "candidates", np.int32)
         safe, valid = self._mask_alive(cand, np.ones(cand.shape, bool))
         self.last_filter_bytes = int(self._C_all.size) * 4
         return safe, valid, nq * st.n_total
@@ -947,10 +948,10 @@ class ShardedBackend(DeltaAwareBackend):
         if self.oblivious:
             bucket = int(self._C_all.shape[0])
             member = pool_membership(nq, pools, bucket, pool_mask=pm)
-            ids = np.asarray(_sharded_oblivious_scan(
+            ids = to_host(_sharded_oblivious_scan(
                 self._C_all, jnp.asarray(Q), jnp.asarray(member),
                 mesh=self.mesh, axis=self.axis,
-                kp=min(kp, bucket)), np.int32)
+                kp=min(kp, bucket)), "candidates", np.int32)
             vout = member[np.arange(nq)[:, None], np.clip(ids, 0, bucket - 1)]
             ids, vout = self._mask_alive(ids, vout)
             evals = nq * bucket + nq * self.ivf.centroids.shape[0]
@@ -965,7 +966,8 @@ class ShardedBackend(DeltaAwareBackend):
             + nq * self.ivf.centroids.shape[0]
         self.last_filter_bytes = (sum(p.size for p in pools) * st.d * 4
                                   + self.ivf.centroids.nbytes)
-        return np.asarray(ids), np.asarray(vout), evals
+        return (to_host(ids, "candidates"), to_host(vout, "valid"),
+                evals)
 
     def _candidates_graph(self, Q_sap: np.ndarray, kp: int,
                           ef_search: int):
@@ -1009,13 +1011,14 @@ class ShardedBackend(DeltaAwareBackend):
                 kp=kp2, ef_cap=ef_cap, max_hops=max_hops,
                 quant=self.quantization or "f32",
                 oblivious=self.oblivious)
-            c = np.asarray(cand, np.int32)
+            c = to_host(cand, "candidates", np.int32)
             ids_p.append(np.where(c >= 0, c + np.int32(lo), -1))
-            d_p.append(np.where(c >= 0, np.asarray(cand_d, np.float32),
+            d_p.append(np.where(c >= 0,
+                                to_host(cand_d, "distances", np.float32),
                                 np.inf))
-            vis_p.append(np.asarray(visited))
-            hops_t += int(np.asarray(hops).sum())
-            edges_t += int(np.asarray(edges).sum())
+            vis_p.append(to_host(visited, "visited"))
+            hops_t += int(to_host(hops, "hops").sum())
+            edges_t += int(to_host(edges, "edges").sum())
         if not ids_p:                  # every shard group is down
             self.last_n_hops = self.last_n_edges_scanned = 0
             self.last_filter_bytes = 0

@@ -1,7 +1,8 @@
 """End-to-end observability: exact span trees on VirtualClock for both
-schedulers and both placements, Prometheus exposition, Chrome-trace
-export, kernel profiling, and disabled-mode no-op guarantees
-(DESIGN.md §13).
+schedulers and both placements, the batch trace of direct requests,
+device->host reads under `sync` spans, the spans' mirror in a
+`jax.profiler` capture, Prometheus exposition, Chrome-trace export, and
+disabled-mode no-op guarantees (DESIGN.md §13).
 
 Every tree test is a SCRIPTED interleaving on the injected
 `VirtualClock`: the recorder runs on the same clock instance as the
@@ -26,8 +27,7 @@ from repro.core import dcpe
 from repro.data import synth
 from repro.obs import (NULL_RECORDER, MetricsRegistry, Observability,
                        TraceRecorder, child_span, current,
-                       profile_kernels, start_metrics_server)
-from repro.obs import profiler as obs_profiler
+                       start_metrics_server, to_host)
 from repro.serving.runtime import (Collection, SlotLoop, VirtualClock)
 from repro.serving.search_engine import SearchStats
 
@@ -85,9 +85,28 @@ def test_flush_two_request_interleaving_exact_tree(ds):
     assert ins["attrs"]["n_rows"] == 64
     assert ins["attrs"]["compacted"] is False
 
-    # batch trace: flush root -> filter + refine engine children
-    (flush,) = rec.tree("t/c:b0")
-    assert _shape(flush) == ("flush", [("filter", []), ("refine", [])])
+    # batch trace: the worker saw r0 at 0 and collected until r1 filled
+    # the bucket at 0.001; assembly, the engine call and delivery take
+    # no virtual time.  The engine's filter and refine end at their
+    # device->host reads (64 rows, all in the delta buffer: its
+    # candidates and distances, then the refined ids)
+    (root,) = rec.tree("t/c:b0")
+    assert _shape(root) == ("batch", [
+        ("collect", []), ("assemble", []),
+        ("flush", [("filter", [("sync", []), ("sync", [])]),
+                   ("refine", [("sync", [])])]),
+        ("deliver", [])])
+    assert root["attrs"] == {"collection": "t/c", "n_real": 2}
+    assert (root["t_start"], root["t_end"]) == (0.0, 0.001)
+    collect, assemble, flush, deliver = root["children"]
+    assert (collect["t_start"], collect["t_end"]) == (0.0, 0.001)
+    for node in (assemble, flush, deliver):
+        assert (node["t_start"], node["t_end"]) == (0.001, 0.001)
+    assert _shape(flush) == ("flush", [
+        ("filter", [("sync", []), ("sync", [])]),
+        ("refine", [("sync", [])])])
+    assert [s["attrs"]["what"] for c in flush["children"]
+            for s in c["children"]] == ["candidates", "distances", "ids"]
     assert flush["attrs"]["n_real"] == 2
     assert flush["attrs"]["bucket"] == 2
     assert flush["attrs"]["backend"] == "flat"
@@ -147,7 +166,9 @@ def test_continuous_scheduler_exact_tree(ds):
         slot = req["children"][1]
         assert slot["attrs"]["batch"] == f"t/s:s{i}"
         (step,) = rec.tree(f"t/s:s{i}")
-        assert _shape(step) == ("step", [("filter", []), ("refine", [])])
+        assert _shape(step) == ("step", [
+            ("filter", [("sync", []), ("sync", [])]),
+            ("refine", [("sync", [])])])
         assert step["attrs"]["n_active"] == 1
         assert step["attrs"]["capacity"] == 2
 
@@ -222,11 +243,13 @@ def test_sharded_placement_emits_per_shard_spans(ds):
     finally:
         col.close()
 
-    (flush,) = rec.tree("t/sh:b0")
+    (root,) = rec.tree("t/sh:b0")
+    flush = root["children"][2]
     expect_shards = [(f"shard{i}", []) for i in range(n_shards)]
-    assert _shape(flush) == ("flush", [("filter", expect_shards),
-                                       ("refine", [])])
-    shards = flush["children"][0]["children"]
+    assert _shape(flush) == ("flush", [
+        ("filter", [("sync", [])] + expect_shards),
+        ("refine", [("sync", [])])])
+    shards = flush["children"][0]["children"][1:]
     assert [s["attrs"]["shard"] for s in shards] == list(range(n_shards))
     assert sum(s["attrs"]["n_alive"] for s in shards) == 64
     assert shards[-1]["attrs"]["row_stop"] >= 64
@@ -331,49 +354,130 @@ def test_start_metrics_server_scrape():
         server.shutdown()
 
 
-# ------------------------------------------------------ kernel profiler
+# --------------------------------------------- batch trace, direct path
 
 
-def test_profiler_records_host_calls_not_traced_calls():
-    """instrument() wrappers record fenced wall time + bytes for host
-    calls, and stay out of the way inside jit traces (Tracer args)."""
+def test_batch_request_opens_a_batch_root_with_engine_spans(ds):
+    """A request of several queries skips the micro-batcher: the
+    collection's direct call opens the `batch` root itself, so the
+    engine's filter/refine and their syncs have a trace to attach to."""
+    spec = IndexSpec(tenant="t", name="direct", d=D,
+                     sap_beta=suggest_beta(ds.base, fraction=0.05), seed=3)
+    owner = DataOwnerClient(spec)
+    C_sap, C_dce = owner.encrypt_vectors(ds.base)
+    query = owner.query_client().encrypt_queries(ds.queries)
+    with SecureAnnService(obs=True) as svc:
+        svc.create_collection(spec)
+        svc.insert("t", "direct", C_sap, C_dce)
+        res = svc.submit(SearchRequest(tenant="t", collection="direct",
+                                       query=query,
+                                       params=SearchParams(k=K)))
+        assert res.ids.shape == (len(ds.queries), K)
+        rec = svc.obs.recorder
+        (root,) = rec.tree("t/direct:d0")
+    assert _shape(root) == ("batch", [
+        ("filter", [("sync", []), ("sync", [])]),
+        ("refine", [("sync", [])])])
+    assert root["attrs"] == {"collection": "t/direct",
+                             "n_real": len(ds.queries)}
+    filt, ref = root["children"]
+    assert filt["attrs"]["nq"] == len(ds.queries)
+    assert root["t_start"] <= filt["t_start"] <= filt["t_end"] \
+        <= ref["t_start"] <= ref["t_end"] <= root["t_end"]
+
+
+# ------------------------------------------- device->host reads: sync
+
+
+@pytest.mark.parametrize("backend,quantization", [
+    ("flat", None), ("flat", "int8"), ("ivf", None)])
+def test_every_device_read_in_a_search_is_a_sync_span(
+        ds, monkeypatch, backend, quantization):
+    """Every host read of a device array during `search_batch` happens
+    inside a `sync` span, one read per span.  Results are committed to a
+    one-device mesh so that every read goes through `ArrayImpl.__array__`
+    (on the CPU, numpy otherwise takes single-device arrays through the
+    buffer protocol, which no hook sees); `int()`/`.item()` reads pass
+    through `_value` as `__array__` does, so `_value` is what counts."""
+    from jax._src.array import ArrayImpl
+
+    reads = []
+    orig = ArrayImpl._value
+
+    def value(self):
+        ctx = current()
+        reads.append(None if ctx is None else ctx[1].name)
+        return orig.fget(self)
+
+    rec = TraceRecorder()
+    col = Collection("t", f"rd-{backend}-{quantization}", D,
+                     sap_beta=dcpe.suggest_beta(ds.base, fraction=0.05),
+                     seed=1, tracer=rec, backend=backend,
+                     quantization=quantization)
+    try:
+        col.insert(ds.base[:128])
+        col.compact()
+        col.insert(ds.base[128:160])          # main and delta rows
+        user = col.new_user()
+        enc = [user.encrypt_query(q) for q in ds.queries]
+        Q = np.stack([c for c, _ in enc])
+        T = np.stack([t for _, t in enc])
+        col.search_batch(Q, T, K)             # attach and compile
+        rec.clear()
+        mesh = jax.make_mesh((1,), ("x",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+        monkeypatch.setattr(ArrayImpl, "_value", property(value))
+        with jax.set_mesh(mesh):
+            ids, _ = col.search_batch(Q, T, K)
+        monkeypatch.undo()
+    finally:
+        col.close()
+    assert ids.shape == (len(ds.queries), K)
+    syncs = [s for s in rec.spans() if s.name == "sync"]
+    assert reads and set(reads) == {"sync"}
+    assert len(reads) == len(syncs)
+    assert {s.trace_id for s in syncs} == {col.tracer.trace_ids()[0]}
+
+
+def test_to_host_without_a_trace_is_a_plain_read():
     import jax.numpy as jnp
 
-    calls = []
-
-    def fn(x):
-        calls.append(type(x).__name__)
-        return x * 2.0
-
-    wrapped = obs_profiler.instrument("test.fn", fn)
-    x = jnp.ones((8, 4), jnp.float32)
-    assert obs_profiler.active_profiler() is None
-    with profile_kernels() as prof:
-        wrapped(x)                         # host call: recorded
-        jax.jit(wrapped)(x)                # trace-time call: skipped
-        assert obs_profiler.active_profiler() is prof
-    assert obs_profiler.active_profiler() is None
-    summary = prof.summary()
-    assert summary["test.fn"]["calls"] == 1
-    assert summary["test.fn"]["total_bytes"] == x.nbytes
-    assert summary["test.fn"]["total_s"] > 0
-    assert len(calls) == 2                 # fn itself ran both times
+    assert current() is None
+    out = to_host(jnp.arange(4), "ids", np.int64)
+    assert isinstance(out, np.ndarray) and out.dtype == np.int64
+    assert out.tolist() == [0, 1, 2, 3]
 
 
-def test_profiler_covers_engine_kernels(ds):
-    """A real search under profile_kernels() attributes device time to
-    the filter kernel entry point."""
+# ---------------------------------------- the profiler's own clock
+
+
+def test_profiler_capture_holds_the_program_spans(ds, tmp_path):
+    """A `jax.profiler` capture around a traced search holds the live
+    spans as host events of the same names, on the capture's clock."""
+    from jax.profiler import ProfileData
+
+    rec = TraceRecorder()
     col = Collection("t", "prof", D, sap_beta=1.0, seed=1, max_batch=2,
-                     max_wait_ms=1.0)
+                     max_wait_ms=1.0, tracer=rec)
     try:
         col.insert(ds.base[:64])
         user = col.new_user()
-        with profile_kernels() as prof:
-            col.search(*user.encrypt_query(ds.queries[0]), K)
-        assert prof.total_seconds("l2_topk") > 0
-        assert prof.total_bytes("l2_topk") > 0
+        enc = user.encrypt_query(ds.queries[0])
+        col.search(*enc, K)                   # compile outside the capture
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            col.search(*enc, K)
+        finally:
+            jax.profiler.stop_trace()
     finally:
         col.close()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events}
+    assert {"batch", "collect", "assemble", "flush", "filter", "sync",
+            "refine", "deliver"} <= names
+    # retroactive per-request spans are not mirrored
+    assert not {"request", "queue", "emit"} & names
 
 
 # ------------------------------------------------------- disabled mode

@@ -205,7 +205,7 @@ def kernel_report(col, k: int = K, nq: int = 32) -> dict:
     Q = jnp.zeros((nq, col.d), jnp.float32)
     report = {}
     if b.kind == "flat" and b.quantization is None:
-        ok = _has_kernel(l2_ops.knn.__wrapped__, Q, b._C_main, kp,
+        ok = _has_kernel(l2_ops.knn, Q, b._C_main, kp,
                          chunk=min(4096, b._C_main.shape[0]),
                          use_kernel=True)
         report["filter"] = "l2_topk" if ok else "xla"
@@ -213,13 +213,13 @@ def kernel_report(col, k: int = K, nq: int = 32) -> dict:
         if not b._use_pallas():
             raise SystemExit(f"{col.name}: ADC filter takes the XLA path")
         if b.quantization == "int8":
-            ok = _has_kernel(adc_ops.sq_knn.__wrapped__,
+            ok = _has_kernel(adc_ops.sq_knn,
                              jnp.zeros((nq, col.d), jnp.int8), b._adc_c8,
                              b._adc_cn, kp, ok=b._adc_ok, use_kernel=True)
             report["filter"] = "sq_adc_topk" if ok else "xla"
         else:
             m = b.adc_codebook.m
-            ok = _has_kernel(adc_ops.pq_knn.__wrapped__,
+            ok = _has_kernel(adc_ops.pq_knn,
                              jnp.zeros((nq, m, 256), jnp.float32),
                              b._adc_codes_t, kp, ok=b._adc_ok,
                              use_kernel=True)

@@ -24,10 +24,17 @@ slots and tombstoned rows (serving/runtime mutable stores hand the
 kernel sentinel-padded power-of-two buffers), so growing deltas reuse
 executables instead of recompiling per row count.
 
-The merge is K rounds of extract-min over the concatenated
-[running-K | tile] buffer — pure VPU min/compare/select ops (no
-lax.sort / lax.top_k inside the kernel), each round masking the
-selected column, so the state stays ascending by construction.
+The merge is a threshold-gated insertion: only tile entries strictly
+below the row's worst kept distance are candidates, and a data-
+dependent loop inserts them one per round, smallest first, at their
+rank in the ascending state (a one-lane shift right, the worst entry
+dropping off).  Pure VPU min/compare/select ops and one lane rotate
+(no lax.sort / lax.top_k inside the kernel).  A tile none of whose
+entries beats its row's threshold costs one compare and one row
+reduction; over a corpus in no particular order the rounds per tile
+fall off as ~K/t, so the whole merge runs about 1% of the K rounds
+per tile a fixed extract-min would.  The kernel counts the rounds it
+ran (`merge_rounds`): a third output, summed over the grid.
 
 VMEM per grid step (defaults): SQ — q8 (128 x d_p) + c8 tile
 (512 x d_p) int8 + int32 state/scratch ~ d_p KiB-scale; PQ — LUT
@@ -43,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..common import HIGHEST, LANE, interpret_default, pad_to, padded_size
 
@@ -54,59 +62,77 @@ PQ_K = 256                   # centroids per subspace (1-byte codes)
 INT_BIG = np.int32(2 ** 30)  # sentinel surrogate distance (int32 path)
 
 
-def _merge_topk(best_d_ref, best_i_ref, d_blk, i_blk, big):
+def _merge_topk(best_d_ref, best_i_ref, rounds_ref, d_blk, base, big, nq):
     """Fold a (bq, bn) distance tile into the (bq, K) running top-k.
 
-    K rounds of extract-min over [running | tile]: per round, the
-    row-wise min and its first column are found with VPU reductions,
-    written into output column t, and masked out of the buffer.  Ties
-    resolve to the first column, i.e. the lowest global id (running
-    entries precede the tile, and tile columns are ascending ids) —
-    the same tie order as `jax.lax.top_k` over the full distance row.
-    Exhausted rounds (min already `big`: fewer than K valid rows seen)
-    emit id -1, never a duplicate of an already-extracted id — callers
-    treat negative ids as empty slots.
+    The state is ascending by (distance, id) and holds the K smallest
+    pairs seen so far; empty slots are (big, -1).  Tile entries below
+    the row's worst kept distance are the candidates.  Each round takes
+    every row's smallest candidate `m` and its first column (ties go to
+    the lowest id, as in `jax.lax.top_k` over the full distance row)
+    and, where `m` is still below the row's worst, inserts it after the
+    kept entries <= m: kept ids are lower (earlier tiles, or earlier
+    columns of this one), so (distance, id) order holds and an entry
+    equal to the worst never enters.  A row whose candidate does not
+    enter is done with the tile.  The loop runs until no row has a
+    candidate left: at most min(bn, K) + 1 rounds, none when the tile
+    holds nothing that can enter.  Tile columns are global ids
+    base + col; rows from nq on pad the query block and merge nothing;
+    the rounds run are added to `rounds_ref`.
     """
-    prev_d = best_d_ref[...]
-    prev_i = best_i_ref[...]
-    bq, K = prev_d.shape
-    cat_d = jnp.concatenate([prev_d, d_blk], axis=1)
-    cat_i = jnp.concatenate([prev_i, i_blk], axis=1)
-    W = cat_d.shape[1]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, W), 1)
+    state_d = best_d_ref[...]
+    state_i = best_i_ref[...]
+    bq, K = state_d.shape
+    bn = d_blk.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
     kcols = jax.lax.broadcasted_iota(jnp.int32, (bq, K), 1)
+    live = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0) < nq
+    cand = jnp.where(live & (d_blk < state_d[:, K - 1:]), d_blk, big)
+    m0 = jnp.min(cand, axis=1, keepdims=True)
 
-    def round_(t, carry):
-        cat, out_d, out_i = carry
-        m = jnp.min(cat, axis=1, keepdims=True)                 # (bq, 1)
-        first = jnp.min(jnp.where(cat == m, cols, W), axis=1,
+    def more(carry):
+        return jnp.min(carry[3]) < big
+
+    def round_(carry):
+        cand, sd, si, m, n = carry
+        first = jnp.min(jnp.where(cand == m, cols, bn), axis=1,
                         keepdims=True)
-        sel = cols == first                                      # one-hot
-        mi = jnp.max(jnp.where(sel, cat_i, -1), axis=1, keepdims=True)
-        mi = jnp.where(m >= big, -1, mi)         # exhausted: empty slot
-        out_d = jnp.where(kcols == t, m, out_d)
-        out_i = jnp.where(kcols == t, mi, out_i)
-        return jnp.where(sel, big, cat), out_d, out_i
+        # rank among the kept entries; K where m is not below the worst
+        pos = jnp.sum((sd <= m).astype(jnp.int32), axis=1, keepdims=True)
+        keep = kcols < pos
+        sd = jnp.where(keep, sd, jnp.where(kcols == pos, m,
+                                           pltpu.roll(sd, 1, 1)))
+        si = jnp.where(keep, si, jnp.where(kcols == pos, base + first,
+                                           pltpu.roll(si, 1, 1)))
+        cand = jnp.where((pos < K) & (cols != first), cand, big)
+        return (cand, sd, si, jnp.min(cand, axis=1, keepdims=True), n + 1)
 
-    _, out_d, out_i = jax.lax.fori_loop(
-        0, K, round_, (cat_d, jnp.full_like(prev_d, big),
-                       jnp.full_like(prev_i, -1)))
-    best_d_ref[...] = out_d
-    best_i_ref[...] = out_i
+    _, state_d, state_i, _, n = jax.lax.while_loop(
+        more, round_, (cand, state_d, state_i, m0, jnp.int32(0)))
+    best_d_ref[...] = state_d
+    best_i_ref[...] = state_i
+    rounds_ref[...] += n
 
 
-def _sq_adc_kernel(q_ref, c_ref, cn_ref, ok_ref, best_d_ref, best_i_ref):
+def _init_state(best_d_ref, best_i_ref, rounds_ref, big):
+    best_d_ref[...] = jnp.full(best_d_ref.shape, big, best_d_ref.dtype)
+    best_i_ref[...] = jnp.full(best_i_ref.shape, -1, jnp.int32)
+    rounds_ref[...] = jnp.zeros(rounds_ref.shape, jnp.int32)
+
+
+def _sq_adc_kernel(q_ref, c_ref, cn_ref, ok_ref, best_d_ref, best_i_ref,
+                   rounds_ref, *, nq):
     """One code tile of the int8 scan: s8 MXU dot + top-k merge.
 
     q_ref: (nq_p, d_p) int8;  c_ref: (bn, d_p) int8;
-    cn_ref/ok_ref: (1, bn) int32;  best_*_ref: (nq_p, K) int32 state.
+    cn_ref/ok_ref: (1, bn) int32;  best_*_ref: (nq_p, K) int32 state;
+    rounds_ref: (1, LANE) int32 merge-round count.
     """
     pi = pl.program_id(0)
 
     @pl.when(pi == 0)
     def _init():
-        best_d_ref[...] = jnp.full(best_d_ref.shape, INT_BIG, jnp.int32)
-        best_i_ref[...] = jnp.full(best_i_ref.shape, -1, jnp.int32)
+        _init_state(best_d_ref, best_i_ref, rounds_ref, INT_BIG)
 
     cross = jax.lax.dot_general(
         q_ref[...], c_ref[...],
@@ -114,12 +140,12 @@ def _sq_adc_kernel(q_ref, c_ref, cn_ref, ok_ref, best_d_ref, best_i_ref):
         preferred_element_type=jnp.int32,
     )
     d_blk = jnp.where(ok_ref[...] > 0, cn_ref[...] - 2 * cross, INT_BIG)
-    bn = d_blk.shape[1]
-    gcol = pi * bn + jax.lax.broadcasted_iota(jnp.int32, d_blk.shape, 1)
-    _merge_topk(best_d_ref, best_i_ref, d_blk, gcol, INT_BIG)
+    _merge_topk(best_d_ref, best_i_ref, rounds_ref, d_blk,
+                pi * d_blk.shape[1], INT_BIG, nq)
 
 
-def _pq_adc_kernel(lut_ref, codes_ref, ok_ref, best_d_ref, best_i_ref):
+def _pq_adc_kernel(lut_ref, codes_ref, ok_ref, best_d_ref, best_i_ref,
+                   rounds_ref, *, nq):
     """One code tile of the PQ scan: one-hot MXU LUT gather + merge.
 
     lut_ref: (nq_p, m_p*256) f32 flattened per-query tables (padded
@@ -131,8 +157,7 @@ def _pq_adc_kernel(lut_ref, codes_ref, ok_ref, best_d_ref, best_i_ref):
 
     @pl.when(pi == 0)
     def _init():
-        best_d_ref[...] = jnp.full(best_d_ref.shape, jnp.inf, jnp.float32)
-        best_i_ref[...] = jnp.full(best_i_ref.shape, -1, jnp.int32)
+        _init_state(best_d_ref, best_i_ref, rounds_ref, jnp.inf)
 
     codes = codes_ref[...].astype(jnp.int32)          # (m_p, bn)
     m_p, bn = codes.shape
@@ -145,14 +170,37 @@ def _pq_adc_kernel(lut_ref, codes_ref, ok_ref, best_d_ref, best_i_ref):
         precision=HIGHEST, preferred_element_type=jnp.float32,
     )                                                  # (nq_p, bn)
     d_blk = jnp.where(ok_ref[...] > 0, d_blk, jnp.inf)
-    gcol = pi * bn + jax.lax.broadcasted_iota(jnp.int32, d_blk.shape, 1)
-    _merge_topk(best_d_ref, best_i_ref, d_blk, gcol, jnp.float32(jnp.inf))
+    _merge_topk(best_d_ref, best_i_ref, rounds_ref, d_blk, pi * bn,
+                jnp.float32(jnp.inf), nq)
 
 
 def _pad_ok(ok: jnp.ndarray, n: int, block_n: int) -> jnp.ndarray:
     """(n,) validity -> (1, n_p) int32 with padded slots invalid."""
     row = ok.astype(jnp.int32)[None, :]
     return pad_to(row, 1, block_n, value=0)
+
+
+def _plan(n: int, kp: int, block_n: int) -> tuple[int, int, int]:
+    """(kp clamped to n, state width K, tile rows) of a scan over n rows."""
+    kp = min(kp, n)
+    return (kp, padded_size(max(kp, 1), LANE),
+            max(LANE, min(block_n, padded_size(n, LANE))))
+
+
+def merge_rounds_full(n: int, kp: int, block_n: int) -> int:
+    """Merge rounds of a fixed K-per-tile merge over n rows: the count a
+    kernel's `merge_rounds` is read against (K x tiles)."""
+    _, K, block_n = _plan(n, kp, block_n)
+    return K * (padded_size(n, block_n) // block_n)
+
+
+def _out_specs(nq_p: int, K: int, dist_dtype):
+    state = pl.BlockSpec((nq_p, K), lambda i: (0, 0))
+    count = pl.BlockSpec((1, LANE), lambda i: (0, 0))
+    return ([state, state, count],
+            [jax.ShapeDtypeStruct((nq_p, K), dist_dtype),
+             jax.ShapeDtypeStruct((nq_p, K), jnp.int32),
+             jax.ShapeDtypeStruct((1, LANE), jnp.int32)])
 
 
 @functools.partial(
@@ -166,21 +214,19 @@ def sq_adc_topk(
     *,
     block_n: int = DEFAULT_BLOCK_N_SQ,
     interpret: bool | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused int8 ADC scan + top-kp.
 
     q8: (nq, d) int8; c8: (n, d) int8; cn: (n,) int32; ok: (n,) row
-    validity -> (dists (nq, kp) int32 ascending, idx (nq, kp) int32).
-    Slots beyond the valid-row count come back as id -1 / dist INT_BIG.
+    validity -> (dists (nq, kp) int32 ascending, idx (nq, kp) int32,
+    merge_rounds () int32).  Slots beyond the valid-row count come back
+    as id -1 / dist INT_BIG.
     """
     if interpret is None:
         interpret = interpret_default()
-    nq, _ = q8.shape
     n = c8.shape[0]
-    kp = min(kp, n)
-    K = padded_size(max(kp, 1), LANE)
-
-    block_n = max(LANE, min(block_n, padded_size(n, LANE)))
+    kp, K, block_n = _plan(n, kp, block_n)
+    nq = q8.shape[0]
     Qp = pad_to(pad_to(q8, 0, INT8_SUBLANE), 1, LANE)
     Cp = pad_to(pad_to(c8, 0, block_n), 1, LANE)
     cnp = pad_to(cn[None, :].astype(jnp.int32), 1, block_n)
@@ -188,27 +234,21 @@ def sq_adc_topk(
     nq_p, d_p = Qp.shape
     n_p = Cp.shape[0]
 
-    grid = (n_p // block_n,)
-    best_d, best_i = pl.pallas_call(
-        _sq_adc_kernel,
-        grid=grid,
+    out_specs, out_shape = _out_specs(nq_p, K, jnp.int32)
+    best_d, best_i, rounds = pl.pallas_call(
+        functools.partial(_sq_adc_kernel, nq=nq),
+        grid=(n_p // block_n,),
         in_specs=[
             pl.BlockSpec((nq_p, d_p), lambda i: (0, 0)),
             pl.BlockSpec((block_n, d_p), lambda i: (i, 0)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((nq_p, K), lambda i: (0, 0)),
-            pl.BlockSpec((nq_p, K), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq_p, K), jnp.int32),
-            jax.ShapeDtypeStruct((nq_p, K), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(Qp, Cp, cnp, okp)
-    return best_d[:nq, :kp], best_i[:nq, :kp]
+    return best_d[:nq, :kp], best_i[:nq, :kp], rounds[0, 0]
 
 
 @functools.partial(
@@ -221,22 +261,20 @@ def pq_adc_topk(
     *,
     block_n: int = DEFAULT_BLOCK_N_PQ,
     interpret: bool | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused PQ ADC scan + top-kp.
 
     lut: (nq, m, 256) f32 per-query tables; codes_t: (m, n) uint8
     transposed codes; ok: (n,) row validity
-    -> (dists (nq, kp) f32 ascending, idx (nq, kp) int32).
+    -> (dists (nq, kp) f32 ascending, idx (nq, kp) int32,
+    merge_rounds () int32).
     """
     if interpret is None:
         interpret = interpret_default()
     nq, m, pqk = lut.shape
     assert pqk == PQ_K
     n = codes_t.shape[1]
-    kp = min(kp, n)
-    K = padded_size(max(kp, 1), LANE)
-
-    block_n = max(LANE, min(block_n, padded_size(n, LANE)))
+    kp, K, block_n = _plan(n, kp, block_n)
     # pad subspaces: zero LUT rows + code 0 -> padded term gathers 0.0
     lut_p = pad_to(pad_to(lut.astype(jnp.float32), 1, INT8_SUBLANE), 0, 8)
     nq_p, m_p, _ = lut_p.shape
@@ -245,23 +283,17 @@ def pq_adc_topk(
     okp = _pad_ok(ok, n, block_n)
     n_p = Cp.shape[1]
 
-    grid = (n_p // block_n,)
-    best_d, best_i = pl.pallas_call(
-        _pq_adc_kernel,
-        grid=grid,
+    out_specs, out_shape = _out_specs(nq_p, K, jnp.float32)
+    best_d, best_i, rounds = pl.pallas_call(
+        functools.partial(_pq_adc_kernel, nq=nq),
+        grid=(n_p // block_n,),
         in_specs=[
             pl.BlockSpec((nq_p, m_p * PQ_K), lambda i: (0, 0)),
             pl.BlockSpec((m_p, block_n), lambda i: (0, i)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((nq_p, K), lambda i: (0, 0)),
-            pl.BlockSpec((nq_p, K), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq_p, K), jnp.float32),
-            jax.ShapeDtypeStruct((nq_p, K), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(lut_flat, Cp, okp)
-    return best_d[:nq, :kp], best_i[:nq, :kp]
+    return best_d[:nq, :kp], best_i[:nq, :kp], rounds[0, 0]

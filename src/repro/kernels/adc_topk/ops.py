@@ -26,6 +26,13 @@ Beyond that, near-ties within a few ulp may round together or swap —
 absorbed by the ADC oversampling + exact-refine contract (core.adc);
 do not write bit-exactness parity tests at larger d.
 
+The id block `sq_knn` / `pq_knn` return carries one more column, the
+same in every row: the kernel's merge-round count (`adc_topk.
+_merge_topk`), -1 from the XLA path, which runs no such merge.  It
+rides the candidate read the engine makes anyway; `split_merge_rounds`
+takes it off on the host, and `merge_rounds_full` (host arithmetic on
+the shapes) gives the K x tiles a fixed merge would run.
+
 `sq_pool_scan` / `pq_pool_scan` are the quantized analogues of the
 engine's `_masked_pruned_scan` for IVF-pruned candidate pools
 (per-query gathers — a gather workload, so they are XLA-only by
@@ -38,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..common import pad_to, running_topk_scan
 from . import adc_topk as _kernel
@@ -46,6 +54,9 @@ from . import ref as _ref  # noqa: F401  (parity tests import through ops)
 sq_adc_topk = _kernel.sq_adc_topk
 pq_adc_topk = _kernel.pq_adc_topk
 INT_BIG = _kernel.INT_BIG
+DEFAULT_BLOCK_N_SQ = _kernel.DEFAULT_BLOCK_N_SQ
+DEFAULT_BLOCK_N_PQ = _kernel.DEFAULT_BLOCK_N_PQ
+merge_rounds_full = _kernel.merge_rounds_full
 
 DEFAULT_CHUNK = 8192
 
@@ -59,6 +70,18 @@ def _chunked_scan(dist_fn, n: int, nq: int, k: int, chunk: int,
     convention as the fused Pallas merge."""
     best_d, best_i = running_topk_scan(dist_fn, n, nq, k, chunk)
     return best_d, jnp.where(best_d >= big, -1, best_i)
+
+
+def _with_rounds(idx: jnp.ndarray, rounds) -> jnp.ndarray:
+    col = jnp.full((idx.shape[0], 1), rounds, jnp.int32)
+    return jnp.concatenate([idx, col], axis=1)
+
+
+def split_merge_rounds(ids: np.ndarray) -> tuple[np.ndarray, int | None]:
+    """Host side of the round column: (ids, merge_rounds), the count
+    None from the XLA path."""
+    rounds = int(ids[0, -1])
+    return ids[:, :-1], (rounds if rounds >= 0 else None)
 
 
 @functools.partial(
@@ -77,10 +100,10 @@ def sq_knn(
     """Top-k by int8 ADC surrogate distance cn - 2*(q8 . c8).
 
     q8: (nq, d) int8; c8: (n, d) int8; cn: (n,) int32; ok: optional
-    (n,) row validity -> (dists (nq, k) ascending, idx (nq, k) int32).
-    Kernel path returns int32 distances, fallback f32 — identical
-    ranking for small-d surrogates (exactness bound in the module
-    docstring).
+    (n,) row validity -> (dists (nq, k) ascending, idx (nq, k + 1)
+    int32, its last column the merge-round count).  Kernel path
+    returns int32 distances, fallback f32 — identical ranking for
+    small-d surrogates (exactness bound in the module docstring).
     """
     nq = q8.shape[0]
     n = c8.shape[0]
@@ -88,8 +111,9 @@ def sq_knn(
     if ok is None:
         ok = jnp.ones((n,), jnp.int32)
     if use_kernel:
-        return _kernel.sq_adc_topk(q8, c8, cn, ok, k, block_n=block_n,
-                                   interpret=interpret)
+        dists, idx, rounds = _kernel.sq_adc_topk(
+            q8, c8, cn, ok, k, block_n=block_n, interpret=interpret)
+        return dists, _with_rounds(idx, rounds)
     chunk = min(DEFAULT_CHUNK, n)
     c8p = pad_to(c8, 0, chunk)
     cnp = pad_to(cn.astype(jnp.float32), 0, chunk)
@@ -107,7 +131,8 @@ def sq_knn(
         return jnp.where(os_[None, :] > 0, cs[None, :] - 2.0 * cross,
                          jnp.float32(INT_BIG))
 
-    return _chunked_scan(dist_fn, n, nq, k, chunk, float(INT_BIG))
+    dists, idx = _chunked_scan(dist_fn, n, nq, k, chunk, float(INT_BIG))
+    return dists, _with_rounds(idx, -1)
 
 
 @functools.partial(
@@ -125,7 +150,8 @@ def pq_knn(
     """Top-k by PQ ADC distance sum_m LUT[m, codes_t[m, i]].
 
     lut: (nq, m, 256) f32; codes_t: (m, n) uint8; ok: optional (n,)
-    row validity -> (dists (nq, k) f32 ascending, idx (nq, k) int32).
+    row validity -> (dists (nq, k) f32 ascending, idx (nq, k + 1)
+    int32, its last column the merge-round count).
     """
     nq = lut.shape[0]
     n = codes_t.shape[1]
@@ -133,8 +159,9 @@ def pq_knn(
     if ok is None:
         ok = jnp.ones((n,), jnp.int32)
     if use_kernel:
-        return _kernel.pq_adc_topk(lut, codes_t, ok, k, block_n=block_n,
-                                   interpret=interpret)
+        dists, idx, rounds = _kernel.pq_adc_topk(
+            lut, codes_t, ok, k, block_n=block_n, interpret=interpret)
+        return dists, _with_rounds(idx, rounds)
     chunk = min(DEFAULT_CHUNK, n)
     ctp = pad_to(codes_t, 1, chunk)
     okp = pad_to(ok.astype(jnp.int32), 0, chunk, value=0)
@@ -147,7 +174,8 @@ def pq_knn(
         g = jnp.take_along_axis(lut, cc, axis=2)    # (nq, m, chunk)
         return jnp.where(os_[None, :] > 0, g.sum(axis=1), jnp.inf)
 
-    return _chunked_scan(dist_fn, n, nq, k, chunk, float(jnp.inf))
+    dists, idx = _chunked_scan(dist_fn, n, nq, k, chunk, float(jnp.inf))
+    return dists, _with_rounds(idx, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("kp",))

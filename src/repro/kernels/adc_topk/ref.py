@@ -39,20 +39,30 @@ def pq_dists(lut: np.ndarray, codes_t: np.ndarray) -> np.ndarray:
     return out
 
 
-def _topk_ascending(d, k: int):
+def _topk_ascending(d, k: int, ok=None, big=None):
+    """Top-k ascending; rows with ok == 0 rank last at `big`, and slots
+    holding `big` come back as id -1 (the kernels' empty-slot rule)."""
     d = jnp.asarray(d)
+    if ok is not None:
+        d = jnp.where(jnp.asarray(ok)[None, :] > 0, d, big)
     # k clamps to the row count, as the kernels' kp = min(kp, n) does
     neg, idx = jax.lax.top_k(-d, min(k, d.shape[-1]))
-    return -neg, idx.astype(jnp.int32)
+    idx = idx.astype(jnp.int32)
+    if ok is not None:
+        idx = jnp.where(-neg >= big, -1, idx)
+    return -neg, idx
 
 
-def sq_knn(q8, c8, cn, k: int):
-    """Exact top-k (ascending surrogate distance) of the SQ oracle."""
+def sq_knn(q8, c8, cn, k: int, ok=None):
+    """Exact top-k (ascending surrogate distance) of the SQ oracle;
+    optional (n,) row validity `ok`."""
     return _topk_ascending(sq_dists(np.asarray(q8), np.asarray(c8),
-                                    np.asarray(cn)), k)
+                                    np.asarray(cn)), k, ok,
+                           np.int32(2 ** 30))
 
 
-def pq_knn(lut, codes_t, k: int):
-    """Exact top-k (ascending surrogate distance) of the PQ oracle."""
+def pq_knn(lut, codes_t, k: int, ok=None):
+    """Exact top-k (ascending surrogate distance) of the PQ oracle;
+    optional (n,) row validity `ok`."""
     return _topk_ascending(pq_dists(np.asarray(lut), np.asarray(codes_t)),
-                           k)
+                           k, ok, np.float32(np.inf))

@@ -305,6 +305,10 @@ class DeltaAwareBackend:
         self.last_filter_bytes = 0
         self.last_n_hops = 0
         self.last_n_edges_scanned = 0
+        # the flat ADC kernels' merge rounds (None where no such kernel
+        # ran), and the K x tiles a fixed merge would run
+        self.last_merge_rounds: int | None = None
+        self.last_merge_rounds_full = 0
         self.last_scan_trace: np.ndarray | None = None
 
     # ------------------------------------------------- mutation hooks
@@ -667,12 +671,17 @@ class DeltaAwareBackend:
             _, idx = adc_ops.sq_knn(jnp.asarray(q8), self._adc_c8,
                                     self._adc_cn, kp2, ok=self._adc_ok,
                                     use_kernel=self._use_pallas())
+            block_n = adc_ops.DEFAULT_BLOCK_N_SQ
         else:
             lut = self.adc_codebook.lut(np.asarray(Q_sap, np.float32))
             _, idx = adc_ops.pq_knn(jnp.asarray(lut), self._adc_codes_t,
                                     kp2, ok=self._adc_ok,
                                     use_kernel=self._use_pallas())
-        cand = to_host(idx, "candidates", np.int32)
+            block_n = adc_ops.DEFAULT_BLOCK_N_PQ
+        cand, self.last_merge_rounds = adc_ops.split_merge_rounds(
+            to_host(idx, "candidates", np.int32))
+        self.last_merge_rounds_full = adc_ops.merge_rounds_full(
+            bucket, kp2, block_n)
         safe, valid = self._mask_alive(cand, np.ones(cand.shape, bool))
         self.last_filter_bytes = self._adc_code_bytes(bucket)
         # rows present (incl. tombstones), matching the f32 flat path's

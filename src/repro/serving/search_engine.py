@@ -418,6 +418,8 @@ class ADCFilter:
         self._c8 = self._cn = self._codes_t = None
         self._n = 0
         self.last_filter_bytes = 0
+        self.last_merge_rounds: int | None = None   # flat kernel only
+        self.last_merge_rounds_full = 0
 
     # --------------------------------------------------------- encoding
 
@@ -460,12 +462,17 @@ class ADCFilter:
                 _, idx = adc_ops.sq_knn(jnp.asarray(q8), self._c8,
                                         self._cn, kp2,
                                         use_kernel=self._use_pallas())
+                block_n = adc_ops.DEFAULT_BLOCK_N_SQ
             else:
                 lut = self.codebook.lut(Q)
                 _, idx = adc_ops.pq_knn(jnp.asarray(lut), self._codes_t,
                                         kp2,
                                         use_kernel=self._use_pallas())
-            cand = to_host(idx, "candidates", np.int32)
+                block_n = adc_ops.DEFAULT_BLOCK_N_PQ
+            cand, self.last_merge_rounds = adc_ops.split_merge_rounds(
+                to_host(idx, "candidates", np.int32))
+            self.last_merge_rounds_full = adc_ops.merge_rounds_full(
+                self._n, kp2, block_n)
             # -1 marks slots beyond the valid-row count (kp' > n); the
             # refine sees them masked, never a wrapped gather index
             valid = cand >= 0
@@ -600,6 +607,10 @@ class SecureSearchEngine:
                     hops=int(getattr(self.backend, "last_n_hops", 0)),
                     edges_scanned=int(
                         getattr(self.backend, "last_n_edges_scanned", 0)))
+            rounds = getattr(self.backend, "last_merge_rounds", None)
+            if rounds is not None:
+                fsp.set(merge_rounds=rounds,
+                        merge_rounds_full=self.backend.last_merge_rounds_full)
         if cand.shape[1] < k:       # uniform (nq, k) contract: -1 fill
             pad = ((0, 0), (0, k - cand.shape[1]))
             cand = np.pad(cand, pad)

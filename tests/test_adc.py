@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.core import adc, dcpe, ppanns
 from repro.data import synth
+from repro.kernels.adc_topk import adc_topk as adc_kernel
 from repro.kernels.adc_topk import ops as adc_ops
 from repro.kernels.adc_topk import ref as adc_ref
 from repro.kernels.l2_topk import ops as l2_ops
@@ -111,6 +112,7 @@ class TestKernelParity:
         dk, ik = adc_ops.sq_knn(jnp.asarray(q8), jnp.asarray(c8),
                                 jnp.asarray(cn), kp, interpret=True,
                                 use_kernel=True)
+        ik, _ = adc_ops.split_merge_rounds(np.asarray(ik))
         dr, ir = adc_ref.sq_knn(q8, c8, cn, kp)
         # int32 math: the fused kernel is bit-exact against the oracle
         np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
@@ -119,7 +121,8 @@ class TestKernelParity:
         # surrogate stays integer-exact, kernels/adc_topk/ops.py)
         _, i_f = adc_ops.sq_knn(jnp.asarray(q8), jnp.asarray(c8),
                                 jnp.asarray(cn), kp, use_kernel=False)
-        np.testing.assert_array_equal(np.asarray(i_f), np.asarray(ir))
+        i_f, _ = adc_ops.split_merge_rounds(np.asarray(i_f))
+        np.testing.assert_array_equal(i_f, np.asarray(ir))
 
     @pytest.mark.parametrize("n,d,m,kp", [(300, 24, 8, 20),
                                           (700, 32, 16, 130)])
@@ -132,13 +135,15 @@ class TestKernelParity:
         lut = cb.lut(Q)
         dk, ik = adc_ops.pq_knn(jnp.asarray(lut), jnp.asarray(codes_t),
                                 kp, interpret=True, use_kernel=True)
+        ik, _ = adc_ops.split_merge_rounds(np.asarray(ik))
         dr, ir = adc_ref.pq_knn(lut, codes_t, kp)
         np.testing.assert_allclose(np.asarray(dk), np.asarray(dr),
                                    rtol=1e-5, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
         _, i_f = adc_ops.pq_knn(jnp.asarray(lut), jnp.asarray(codes_t),
                                 kp, use_kernel=False)
-        np.testing.assert_array_equal(np.asarray(i_f), np.asarray(ir))
+        i_f, _ = adc_ops.split_merge_rounds(np.asarray(i_f))
+        np.testing.assert_array_equal(i_f, np.asarray(ir))
 
     def test_ok_mask_excludes_rows(self):
         """Invalid rows (tombstones / bucket padding) never rank ahead
@@ -155,14 +160,16 @@ class TestKernelParity:
             _, ids = adc_ops.sq_knn(
                 jnp.asarray(q8), jnp.asarray(c8), jnp.asarray(cn), 30,
                 ok=jnp.asarray(ok), interpret=True, use_kernel=use_kernel)
-            assert (np.asarray(ids) >= 150).all()
+            ids, _ = adc_ops.split_merge_rounds(np.asarray(ids))
+            assert (ids >= 150).all()
         pq = adc.PQCodebook.train(C, m=8, seed=0)
         codes_t = np.ascontiguousarray(pq.encode(C).T)
         for use_kernel in (True, False):
             _, ids = adc_ops.pq_knn(
                 jnp.asarray(pq.lut(Q)), jnp.asarray(codes_t), 30,
                 ok=jnp.asarray(ok), interpret=True, use_kernel=use_kernel)
-            assert (np.asarray(ids) >= 150).all()
+            ids, _ = adc_ops.split_merge_rounds(np.asarray(ids))
+            assert (ids >= 150).all()
 
     def test_exhausted_merge_emits_empty_slots_not_duplicates(self):
         """kp' beyond the valid-row count must yield -1 slots, never a
@@ -180,7 +187,7 @@ class TestKernelParity:
             _, ids = adc_ops.sq_knn(
                 jnp.asarray(q8), jnp.asarray(c8), jnp.asarray(cn), 30,
                 ok=jnp.asarray(ok), interpret=True, use_kernel=use_kernel)
-            ids = np.asarray(ids)
+            ids, _ = adc_ops.split_merge_rounds(np.asarray(ids))
             assert (ids[:, :12] < 12).all() and (ids[:, :12] >= 0).all()
             assert (ids[:, 12:] == -1).all(), use_kernel
             for row in ids:                 # no duplicates among real ids
@@ -209,6 +216,135 @@ class TestKernelParity:
             expect = pools[qi][np.argsort(pool_d, kind="stable")[:15]]
             np.testing.assert_array_equal(
                 np.sort(d_all[qi][ids[qi]]), np.sort(d_all[qi][expect]))
+
+
+# ---------------------------------------------------------------------------
+# Threshold-gated merge: bit parity where ties, arrival order, empty
+# slots and tombstoned tiles stress it, and its round counter.
+# ---------------------------------------------------------------------------
+
+SQ_BN = adc_kernel.DEFAULT_BLOCK_N_SQ
+PQ_BN = adc_kernel.DEFAULT_BLOCK_N_PQ
+
+
+def _ordered_rows(rng, q, n, d, descending):
+    """n int8 rows of pairwise distinct distance to query q, ordered
+    far to near (descending) or near to far."""
+    pool = rng.integers(-20, 21, (8 * n, d)).astype(np.int8)
+    cn = (pool.astype(np.int32) ** 2).sum(1)
+    dist = cn - 2 * (pool.astype(np.int32) @ q.astype(np.int32))
+    _, first = np.unique(dist, return_index=True)
+    assert first.size >= n
+    rows = rng.choice(first, n, replace=False)
+    rows = rows[np.argsort(dist[rows])]
+    return pool[rows[::-1] if descending else rows]
+
+
+def _sq_case(case, nq=3, seed=0):
+    """(q8, c8, cn, ok, kp) of one named stress case over 4 tiles."""
+    rng = np.random.default_rng(seed)
+    n, d, kp = 4 * SQ_BN, 16, 100
+    lo, hi = (-1, 2) if case == "ties" else (-20, 21)
+    q8 = rng.integers(lo, hi, (nq, d)).astype(np.int8)
+    c8 = rng.integers(lo, hi, (n, d)).astype(np.int8)
+    ok = np.ones(n, np.int32)
+    if case in ("far_to_near", "near_to_far"):
+        c8 = _ordered_rows(rng, q8[0], n, d, case == "far_to_near")
+    elif case == "n_below_kp":          # -1 slots past the 100 rows
+        c8, ok, kp = c8[:100], ok[:100], 160
+    elif case == "tombstone_tiles":     # whole dead tiles between live
+        ok[SQ_BN: 3 * SQ_BN] = 0
+        ok[rng.random(n) < 0.1] = 0
+    elif case == "kp_200":
+        kp = 200
+    cn = (c8.astype(np.int32) ** 2).sum(1).astype(np.int32)
+    return q8, c8, cn, ok, kp
+
+
+def _sq_gated(q8, c8, cn, ok, kp):
+    """Kernel (interpret mode) -> (dists, ids, rounds, full, tiles)."""
+    dk, ik = adc_ops.sq_knn(jnp.asarray(q8), jnp.asarray(c8),
+                            jnp.asarray(cn), kp, ok=jnp.asarray(ok),
+                            interpret=True, use_kernel=True)
+    ids, rounds = adc_ops.split_merge_rounds(np.asarray(ik))
+    n = c8.shape[0]
+    tiles = -(-n // SQ_BN)
+    full = adc_ops.merge_rounds_full(n, kp, SQ_BN)
+    assert full == 128 * -(-min(kp, n) // 128) * tiles      # K x tiles
+    return np.asarray(dk), ids, rounds, full, tiles
+
+
+class TestGatedMerge:
+    @pytest.mark.parametrize("case", [
+        "random", "ties", "far_to_near", "near_to_far", "n_below_kp",
+        "tombstone_tiles", "kp_200"])
+    def test_sq_bit_exact_vs_oracle(self, case):
+        q8, c8, cn, ok, kp = _sq_case(case)
+        dk, ik, rounds, full, tiles = _sq_gated(q8, c8, cn, ok, kp)
+        dr, ir = adc_ref.sq_knn(q8, c8, cn, kp, ok=ok)
+        np.testing.assert_array_equal(dk, np.asarray(dr))
+        np.testing.assert_array_equal(ik, np.asarray(ir))
+        # never more than K + 1 rounds a tile (full = K x tiles)
+        assert 0 < rounds <= full + tiles
+
+    @pytest.mark.parametrize("nq", [1, 32, 256])
+    def test_sq_bit_exact_at_batch(self, nq):
+        q8, c8, cn, ok, kp = _sq_case("random", nq=nq, seed=nq)
+        dk, ik, rounds, full, tiles = _sq_gated(q8, c8, cn, ok, kp)
+        dr, ir = adc_ref.sq_knn(q8, c8, cn, kp, ok=ok)
+        np.testing.assert_array_equal(dk, np.asarray(dr))
+        np.testing.assert_array_equal(ik, np.asarray(ir))
+        assert 0 < rounds <= full + tiles
+
+    def test_sq_empty_slots_are_minus_one(self):
+        q8, c8, cn, ok, kp = _sq_case("n_below_kp")
+        dk, ik, _, _, _ = _sq_gated(q8, c8, cn, ok, kp)
+        assert (ik[:, 100:] == -1).all()
+        assert (dk[:, 100:] == adc_ops.INT_BIG).all()
+        assert (np.sort(ik[:, :100], axis=1) == np.arange(100)).all()
+
+    @pytest.mark.parametrize("nq", [1, 5])
+    def test_pq_bit_exact_on_tied_sums(self, nq):
+        """Small-integer LUT entries: every sum is exact in f32 in any
+        order, and thousands of rows tie."""
+        rng = np.random.default_rng(nq)
+        m, n, kp = 8, 700, 130
+        lut = rng.integers(0, 3, (nq, m, 256)).astype(np.float32)
+        codes_t = rng.integers(0, 256, (m, n)).astype(np.uint8)
+        ok = (rng.random(n) > 0.1).astype(np.int32)
+        dk, ik = adc_ops.pq_knn(jnp.asarray(lut), jnp.asarray(codes_t), kp,
+                                ok=jnp.asarray(ok), interpret=True,
+                                use_kernel=True)
+        ids, rounds = adc_ops.split_merge_rounds(np.asarray(ik))
+        full = adc_ops.merge_rounds_full(n, kp, PQ_BN)
+        dr, ir = adc_ref.pq_knn(lut, codes_t, kp, ok=ok)
+        np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
+        np.testing.assert_array_equal(ids, np.asarray(ir))
+        assert 0 < rounds <= full + -(-n // PQ_BN)
+
+    def test_far_to_near_inserts_k_entries_every_tile(self):
+        q8, c8, cn, ok, kp = _sq_case("far_to_near", nq=1)
+        _, _, rounds, full, tiles = _sq_gated(q8, c8, cn, ok, kp)
+        assert full <= rounds <= full + tiles
+
+    def test_near_to_far_merges_nothing_after_the_first_tile(self):
+        q8, c8, cn, ok, kp = _sq_case("near_to_far", nq=1)
+        _, _, rounds, full, tiles = _sq_gated(q8, c8, cn, ok, kp)
+        assert rounds <= full // tiles + tiles
+
+    @pytest.mark.parametrize("quant", ["int8", "pq8"])
+    def test_xla_path_counts_no_rounds(self, quant):
+        q8, c8, cn, ok, kp = _sq_case("random")
+        if quant == "int8":
+            _, ik = adc_ops.sq_knn(jnp.asarray(q8), jnp.asarray(c8),
+                                   jnp.asarray(cn), kp, use_kernel=False)
+        else:
+            lut = np.ones((3, 4, 256), np.float32)
+            codes_t = np.zeros((4, c8.shape[0]), np.uint8)
+            _, ik = adc_ops.pq_knn(jnp.asarray(lut), jnp.asarray(codes_t),
+                                   kp, use_kernel=False)
+        ids, rounds = adc_ops.split_merge_rounds(np.asarray(ik))
+        assert ids.shape == (3, kp) and rounds is None
 
 
 # ---------------------------------------------------------------------------

@@ -41,3 +41,25 @@ def test_phases_pass_their_checks_at_small_scale():
 def test_phase_cuts_are_stated(phase):
     """A phase that runs below the corpus size says why."""
     assert (phase.n is None) == (phase.cut == "")
+
+
+def test_kernel_check_lowers_the_served_programs():
+    """`_has_kernel` lowers the ops' own jitted programs: on the CPU it
+    finds no TPU custom call, and raises nothing."""
+    import jax.numpy as jnp
+
+    from repro.kernels.adc_topk import ops as adc_ops
+    from repro.kernels.l2_topk import ops as l2_ops
+
+    n, d, m, k = 256, 16, 4, 8
+    ok = jnp.ones((n,), jnp.int32)
+    assert not chip_smoke._has_kernel(
+        l2_ops.knn, jnp.zeros((2, d), jnp.float32),
+        jnp.zeros((n, d), jnp.float32), k, chunk=128, use_kernel=False)
+    assert not chip_smoke._has_kernel(
+        adc_ops.sq_knn, jnp.zeros((2, d), jnp.int8),
+        jnp.zeros((n, d), jnp.int8), jnp.zeros((n,), jnp.int32), k,
+        ok=ok, use_kernel=False)
+    assert not chip_smoke._has_kernel(
+        adc_ops.pq_knn, jnp.zeros((2, m, 256), jnp.float32),
+        jnp.zeros((m, n), jnp.uint8), k, ok=ok, use_kernel=False)

@@ -439,6 +439,49 @@ def test_every_device_read_in_a_search_is_a_sync_span(
     assert {s.trace_id for s in syncs} == {col.tracer.trace_ids()[0]}
 
 
+@pytest.mark.parametrize("quantization", ["int8", "pq8"])
+def test_merge_rounds_ride_the_candidate_read(ds, monkeypatch,
+                                              quantization):
+    """The flat ADC kernel's merge-round count reaches the `filter` span
+    (`merge_rounds`, beside the host's `merge_rounds_full`) inside the
+    candidate read the filter makes anyway: each batch trace holds as many `sync` spans as
+    the XLA path's, which counts no rounds.  The kernel runs in
+    interpret mode here, as the chip's choice of path is steered."""
+    from repro.serving.runtime.ingest import DeltaAwareBackend
+
+    rec = TraceRecorder()
+    col = Collection("t", f"mr-{quantization}", D,
+                     sap_beta=dcpe.suggest_beta(ds.base, fraction=0.05),
+                     seed=1, tracer=rec, quantization=quantization)
+    user = col.new_user()
+    enc = [user.encrypt_query(q) for q in ds.queries]
+    Q = np.stack([c for c, _ in enc])
+    T = np.stack([t for _, t in enc])
+    syncs, filters = {}, {}
+    try:
+        col.insert(ds.base)
+        for kernel in (False, True):
+            monkeypatch.setattr(DeltaAwareBackend, "_use_pallas",
+                                lambda self, on=kernel: on)
+            rec.clear()
+            col.search_batch(Q, T, K)
+            (root,) = [s for s in rec.spans()
+                       if s.name == "batch" and s.parent_id is None]
+            syncs[kernel] = sum(1 for s in rec.spans()
+                                if s.name == "sync"
+                                and s.trace_id == root.trace_id)
+            (filters[kernel],) = [s.attrs for s in rec.spans()
+                                  if s.name == "filter"]
+    finally:
+        col.close()
+    assert syncs[True] == syncs[False] == 2
+    assert "merge_rounds" not in filters[False]
+    attrs = filters[True]
+    assert attrs["merge_rounds"] > 0
+    assert attrs["merge_rounds_full"] > 0
+    assert attrs["merge_rounds_full"] % 128 == 0       # K x tiles
+
+
 def test_to_host_without_a_trace_is_a_plain_read():
     import jax.numpy as jnp
 

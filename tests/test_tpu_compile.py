@@ -98,6 +98,18 @@ def test_sq_adc_topk_compiles(one_chip):
                    _spec(one_chip, (N,), jnp.int32))
 
 
+@pytest.mark.parametrize("nq", [NQ, 256])
+def test_gated_sq_knn_compiles(one_chip, nq):
+    """The int8 filter as the engine calls it (k' = 320): the gated
+    merge's data-dependent loop and lane rotate at the micro-batcher's
+    bucket and at a 256-row batch request."""
+    fn = functools.partial(adc_ops.sq_knn.__wrapped__, k=320,
+                           interpret=False, use_kernel=True)
+    _compiled_text(fn, _spec(one_chip, (nq, 128), jnp.int8),
+                   _spec(one_chip, (N, 128), jnp.int8),
+                   _spec(one_chip, (N,), jnp.int32))
+
+
 def test_pq_adc_topk_compiles(one_chip):
     fn = functools.partial(adc_ops.pq_knn.__wrapped__, k=320,
                            interpret=False, use_kernel=True)

@@ -4,6 +4,11 @@ The data owner encrypts the corpus with `DataOwnerClient.encrypt_vectors`
 and the service ingests it through `insert` and `compact`, as users do.
 Queries are encrypted on the client side under the user's keys, each
 request with fresh randomness, before the window opens.
+
+A configuration may name its placement, e.g. `"placement": {"kind":
+"sharded", "n_shards": 4}`: the collection is then created with that
+`PlacementSpec`.  Without the key it is created in single placement
+on one chip, the service's default.
 """
 
 from __future__ import annotations
@@ -42,6 +47,14 @@ def index_spec(config: dict, seed: int):
     return spec, seeds
 
 
+def placement(config: dict):
+    """The configuration's `PlacementSpec`: single placement where it
+    names none."""
+    from repro.api import PlacementSpec
+
+    return PlacementSpec(**config.get("placement", {}))
+
+
 def build(config: dict, base: np.ndarray, seed: int, *, obs=None,
           log=print) -> Deployment:
     """Encrypt `base` as its owner, then create and fill the collection."""
@@ -50,18 +63,21 @@ def build(config: dict, base: np.ndarray, seed: int, *, obs=None,
     from repro.api import DataOwnerClient, SecureAnnService
 
     spec, seeds = index_spec(config, seed)
+    place = placement(config)
     owner = DataOwnerClient(spec)
     t0 = time.perf_counter()
     C_sap, C_dce = owner.encrypt_vectors(base, seed=seeds[1])
     t1 = time.perf_counter()
     svc = SecureAnnService(obs=obs)
-    svc.create_collection(spec)
+    svc.create_collection(spec, placement=place)
     svc.insert(spec.tenant, spec.name, C_sap, C_dce)
     svc.compact(spec.tenant, spec.name)
     t2 = time.perf_counter()
+    placed = svc.placement(spec.tenant, spec.name)
     log(f"setup encrypt_s={t1 - t0:.3f} ingest_s={t2 - t1:.3f} "
         f"rows={C_sap.shape[0]} dce_bytes={C_dce.nbytes} "
-        f"beta={spec.sap_beta:.6f}")
+        f"beta={spec.sap_beta:.6f} placement={placed.kind} "
+        f"shards={placed.n_shards or 1}")
     return Deployment(svc, spec, owner.share_keys(), seeds)
 
 

@@ -13,6 +13,7 @@ import importlib.util
 import json
 import logging
 import pathlib
+import resource
 import shutil
 import sys
 import tempfile
@@ -226,7 +227,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     layer = cell_metrics(bench, "per_layer", workload)
     readers = {m["name"]: load_metric(m["name"], root / "bench")
                for m in layer}
-    devices = jax.devices()
+    # a cell's chips and its placement agree before any set-up: a
+    # four-chip cell never runs on one chip, a one-chip cell never shards
+    chips = int(cell["chips"])
+    place = deployment.placement(config)
+    try:
+        shards = place.resolve(len(jax.devices())).n_shards or 1
+    except ValueError:                 # more shards than devices
+        shards = place.n_shards
+    if shards != chips:
+        raise ValueError(
+            f"{workload} asks for {chips} chip(s), but the placement of "
+            f"configuration {cell['config']!r} spreads it over {shards}")
+    # the chips the cell runs on: memory is read on each of them
+    devices = jax.devices()[:chips]
     device = devices[0]
     counter = CompileCounter()
     misses = CacheMisses()
@@ -275,8 +289,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t_closed = time.monotonic()
     gc.unfreeze()
     jit1, comp1 = jit_cache_size(), counter.snapshot()
-    mem = device.memory_stats() or {}
-    memory_peak = int(mem.get("peak_bytes_in_use", 0))
+    memory_by_device = [
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in devices]
+    memory_peak = max(memory_by_device)        # the fullest chip
+    # ru_maxrss is in KiB on Linux
+    host_rss_peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
     spans = ([s.to_dict() for s in obs.recorder.spans()
               if t0 <= s.t_start and s.t_end <= t_closed]
              if obs is not None else [])
@@ -286,7 +304,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         trace_events, trace_window, offset = prof.events()
         trace_window = devtrace.observed(trace_events, trace_window)
         trace_events = devtrace.clip(trace_events, *trace_window)
-    col_bytes = sum(a.nbytes for a in _device_arrays(dep.collection))
+    col_bytes = _collection_bytes(dep.collection, devices)
     dep.svc.drop_collection(dep.spec.tenant, dep.spec.name)
     dep.svc.close()
     del dep, obs
@@ -318,7 +336,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                               "max": float(late.max() * 1e3)
                               if late.size else 0.0},
         "memory_peak_bytes": memory_peak,
-        "collection_device_bytes": col_bytes,
+        "memory_peak_bytes_by_device": memory_by_device,
+        "collection_device_bytes": sum(col_bytes),
+        "collection_device_bytes_by_device": col_bytes,
+        "host_rss_peak_bytes": host_rss_peak,
         "rows_per_call": rows_per_call,
         "setup_s": setup_s,
         # answers per whole second of the window, and of the drain after
@@ -358,7 +379,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         values["p50_ms"] = stats.percentile(lat, 50)
         values["p99_ms"] = stats.percentile(lat, 99)
     dev = {"platform": device.platform, "kind": device.device_kind,
-           "count": len(devices), "memory_peak_bytes": memory_peak}
+           "count": len(jax.devices()), "memory_peak_bytes": memory_peak,
+           "memory_peak_bytes_by_device": memory_by_device}
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": refused + lost}
     if trace:
@@ -417,11 +439,13 @@ def _rows_per_call(spans: list, nq: int) -> float:
     return sum(rows) / len(rows) if rows else float(nq)
 
 
-def _device_arrays(col):
+def _collection_bytes(col, devices) -> list[int]:
+    """Bytes on each of `devices` of the arrays the collection's backend
+    and engine hold (corpus, codes, masks): every array's shards summed
+    per device, so a replicated array counts on each chip it is on."""
     import jax
-    seen = {}
-    for obj in (col._backend, col._engine):
-        for a in vars(obj).values():
-            if isinstance(a, jax.Array):
-                seen[id(a)] = a
-    return seen.values()
+    arrays = {id(a): a for obj in (col._backend, col._engine)
+              for a in vars(obj).values() if isinstance(a, jax.Array)}
+    return [sum(s.data.nbytes for a in arrays.values()
+                for s in a.addressable_shards if s.device == d)
+            for d in devices]

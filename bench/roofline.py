@@ -5,6 +5,12 @@ for the work its stage needs, the larger of operations over the peak
 rate and bytes over the memory bandwidth, divided by the device time
 the trace gives the stage's program.  The work is counted from shapes by
 each reader's own function, never from the program.
+
+On a cell of several chips (a sharded placement) the trace's device time
+of a program is summed over the chips (`devtrace.program`).  A reader
+then counts the work of the whole corpus against one chip's peak: the
+whole work over the summed time of all chips is the same share as each
+chip's part of the work over its own time.
 """
 
 from __future__ import annotations

@@ -80,7 +80,11 @@ def test_each_traffic_kind_runs_correct_with_no_compile_in_the_window(
     assert set(facts["compiles_in_window"].values()) == {0}
     assert list(res)[-1] == "checks"
     assert set(res["device"]) >= {"platform", "kind", "count",
-                                  "memory_peak_bytes"}
+                                  "memory_peak_bytes",
+                                  "memory_peak_bytes_by_device"}
+    assert len(res["device"]["memory_peak_bytes_by_device"]) == 1
+    assert len(facts["collection_device_bytes_by_device"]) == 1
+    assert facts["host_rss_peak_bytes"] > 0
 
 
 def test_a_traced_run_reports_per_layer_metrics_from_spans(capsys):
